@@ -111,5 +111,8 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     for name, val, note in run():
         print(f"{name},{val:.2f},{note}")

@@ -35,6 +35,10 @@ def main(argv=None) -> None:
                       help="tiny sizes, 1 repeat (CI tripwire)")
     opts = args.parse_args(argv)
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from benchmarks import (
         batching,
         cluster,
@@ -54,9 +58,11 @@ def main(argv=None) -> None:
         serialise_rows[:] = serialisation.run(smoke=smoke)
         return serialise_rows
 
+    # the sections that fork worker processes run first: a child forked
+    # after this process initialised a JAX backend would contend for (or
+    # hang on) the accelerator the parent holds
     sections = [
         ("offload_overhead (paper Fig. 3)", offload_overhead.run),
-        ("device_dispatch", device_dispatch.run),
         ("registry_scaling", registry_scaling.run),
         ("serialisation", serialisation_section),
         ("putget", putget.run),
@@ -64,6 +70,7 @@ def main(argv=None) -> None:
          lambda smoke=False: batching.run(
              smoke=smoke, serialise_rows=serialise_rows or None)),
         ("cluster (scheduler pipelining -> BENCH_cluster.json)", cluster.run),
+        ("device_dispatch", device_dispatch.run),
         ("serving (worker-driven continuous batching -> BENCH_serving.json)",
          serving.run),
     ]

@@ -375,5 +375,8 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
 if __name__ == "__main__":
     import sys
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     for name, val, note in run(smoke="--smoke" in sys.argv):
         print(f"{name},{val:.3f},{note}")

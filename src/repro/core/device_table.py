@@ -82,14 +82,15 @@ class DeviceHandlerTable:
 
     # -- build the compiled switch table ------------------------------------
 
-    def validate(self, payload_spec: Any) -> Any:
+    def validate(self, payload_spec: Any, *operand_specs: Any) -> Any:
         """All branches must agree on the result spec for ``payload_spec``.
 
         Returns the common result spec.  ``jax.eval_shape`` costs no device
         memory — this is the registration-time type check, the analogue of
         the upcast being statically sound in C++.
         """
-        specs = [jax.eval_shape(h.fn, payload_spec) for h in self.handlers]
+        specs = [jax.eval_shape(h.fn, payload_spec, *operand_specs)
+                 for h in self.handlers]
         ref_tree = jax.tree_util.tree_structure(specs[0])
         ref_leaves = jax.tree_util.tree_leaves(specs[0])
         for h, s in zip(self.handlers[1:], specs[1:]):
@@ -109,37 +110,26 @@ class DeviceHandlerTable:
     def build(
         self,
         payload_spec: Any,
-        *,
+        *operand_specs: Any,
         donate_payload: bool = False,
-        jit: bool = True,
     ) -> Callable:
-        """Compile ``dispatch(key, payload)``.
+        """Compile ``dispatch(key, payload, *operands)``.
 
         ``donate_payload=True`` donates the payload buffers (serving loops
         thread a state pytree through the table; donation makes the update
         in-place on device — essential for multi-GB KV caches).
+
+        ``operands`` are read-only inputs every branch receives after the
+        payload — the serving table's model weights.  They travel as
+        arguments, never donated: an array a branch closed over instead
+        would be lowered into the executable as a constant, so every
+        executable would carry its own copy of the weights.
         """
-        self.validate(payload_spec)
+        self.validate(payload_spec, *operand_specs)
         branches = [h.fn for h in self.handlers]
 
-        def dispatch(key, payload):
-            return jax.lax.switch(key, branches, payload)
+        def dispatch(key, payload, *operands):
+            return jax.lax.switch(key, branches, payload, *operands)
 
-        if not jit:
-            return dispatch
         donate = (1,) if donate_payload else ()
         return jax.jit(dispatch, donate_argnums=donate)
-
-    def lower(self, payload_spec: Any, key_spec=None, **jit_kw):
-        """Lower (no execution) — used by the dry-run and benchmarks."""
-        import jax.numpy as jnp
-
-        self.validate(payload_spec)
-        branches = [h.fn for h in self.handlers]
-
-        def dispatch(key, payload):
-            return jax.lax.switch(key, branches, payload)
-
-        if key_spec is None:
-            key_spec = jax.ShapeDtypeStruct((), jnp.int32)
-        return jax.jit(dispatch, **jit_kw).lower(key_spec, payload_spec)

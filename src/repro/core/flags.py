@@ -81,19 +81,21 @@ MSG_ID_SENTINELS: dict[str, int] = {
 #: worker-driven serving path, docs/serving.md).  A tiny shared namespace
 #: like the flag bits: host and workers must agree on these across
 #: versions, so they live here, not in the serving modules.  ``TOKEN`` and
-#: ``DONE`` messages carry a real token; ``CANCELLED``/``EXPIRED`` are
-#: end-of-stream markers whose token field is a placeholder (-1).
+#: ``DONE`` messages carry a real token; ``CANCELLED``/``EXPIRED``/``FAILED``
+#: are end-of-stream markers whose token field is a placeholder (-1).
 SERVE_STREAM_STATUS: dict[str, int] = {
     "STREAM_TOKEN": 0,      # one decoded token, request still running
     "STREAM_DONE": 1,       # final token: the request reached its budget
     "STREAM_CANCELLED": 2,  # request cancelled; slot freed, no token
     "STREAM_EXPIRED": 3,    # request deadline passed; slot freed, no token
+    "STREAM_FAILED": 4,     # the worker's decode loop raised; no token
 }
 
 STREAM_TOKEN = SERVE_STREAM_STATUS["STREAM_TOKEN"]
 STREAM_DONE = SERVE_STREAM_STATUS["STREAM_DONE"]
 STREAM_CANCELLED = SERVE_STREAM_STATUS["STREAM_CANCELLED"]
 STREAM_EXPIRED = SERVE_STREAM_STATUS["STREAM_EXPIRED"]
+STREAM_FAILED = SERVE_STREAM_STATUS["STREAM_FAILED"]
 
 
 def _validate() -> None:

@@ -24,13 +24,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                    *, bk, scale, nk):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -39,7 +38,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0]
+    length = len_ref[b]
 
     @pl.when(ik * bk < length)
     def _compute():
@@ -78,8 +77,9 @@ def decode_attention(q, k, v, lengths, *, block_k=512, interpret=False):
         kernel,
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ik: (b,),
-                         memory_space=pltpu.SMEM),
+            # the whole (B,) lengths vector sits in SMEM: a rank-1 block
+            # must span the array or a multiple of 128 entries
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, qpk, d), lambda b, h, ik: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b, h, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b, h, ik: (b, h, ik, 0)),
@@ -91,7 +91,7 @@ def decode_attention(q, k, v, lengths, *, block_k=512, interpret=False):
             pltpu.VMEM((qpk, 1), jnp.float32),
             pltpu.VMEM((qpk, 1), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

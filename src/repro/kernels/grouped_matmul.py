@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nd):
     kd = pl.program_id(3)
@@ -62,7 +60,7 @@ def grouped_matmul(x, w, *, block_c=128, block_f=128, block_d=512,
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, ic, jf, kd: (e, ic, jf)),
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _ssd_kernel(x_ref, dt_ref, ll_ref, b_ref, c_ref, h0_ref, y_ref, hN_ref,
                 h_ref, *, L, nc):
@@ -35,8 +33,8 @@ def _ssd_kernel(x_ref, dt_ref, ll_ref, b_ref, c_ref, h0_ref, y_ref, hN_ref,
         h_ref[...] = h0_ref[0].astype(jnp.float32)
 
     x = x_ref[0, 0].astype(jnp.float32)       # (L, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)     # (L,)
-    ll = ll_ref[0, 0].astype(jnp.float32)     # (L,) log lambda (negative)
+    dt = dt_ref[0, ic].astype(jnp.float32)    # (L,)
+    ll = ll_ref[0, ic].astype(jnp.float32)    # (L,) log lambda (negative)
     Bm = b_ref[0, 0].astype(jnp.float32)      # (L, N)
     Cm = c_ref[0, 0].astype(jnp.float32)      # (L, N)
 
@@ -85,8 +83,10 @@ def ssd_chunked_kernel(x, dt, loglam, Bm, Cm, h0=None, *, chunk=256,
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, 1, L, P), lambda bh, ic: (bh, ic, 0, 0)),
-            pl.BlockSpec((1, 1, L), lambda bh, ic: (bh, ic, 0)),
-            pl.BlockSpec((1, 1, L), lambda bh, ic: (bh, ic, 0)),
+            # gates: every chunk of a row at once — a (1, L) tile of the
+            # (nc, L) gate matrix would break the TPU's (8, 128) tiling
+            pl.BlockSpec((1, nc, L), lambda bh, ic: (bh, 0, 0)),
+            pl.BlockSpec((1, nc, L), lambda bh, ic: (bh, 0, 0)),
             pl.BlockSpec((1, 1, L, N), lambda bh, ic: (bh, ic, 0, 0)),
             pl.BlockSpec((1, 1, L, N), lambda bh, ic: (bh, ic, 0, 0)),
             pl.BlockSpec((1, N, P), lambda bh, ic: (bh, 0, 0)),
@@ -100,7 +100,7 @@ def ssd_chunked_kernel(x, dt, loglam, Bm, Cm, h0=None, *, chunk=256,
             jax.ShapeDtypeStruct((BH, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -28,8 +28,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, c0_ref, n0_ref, m0_ref,
                   h_ref, cN_ref, nN_ref, mN_ref, C_ref, n_ref, m_ref, *,
@@ -39,14 +37,14 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, c0_ref, n0_ref, m0_ref,
     @pl.when(ic == 0)
     def _init():
         C_ref[...] = c0_ref[0].astype(jnp.float32)
-        n_ref[...] = n0_ref[0:1].astype(jnp.float32)   # (1, dk)
-        m_ref[...] = m0_ref[0:1].astype(jnp.float32)   # (1, 1)
+        n_ref[...] = n0_ref[0].astype(jnp.float32)     # (1, dk)
+        m_ref[...] = m0_ref[0].astype(jnp.float32)     # (1, 1)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale         # (L, dk)
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)                 # (L, dv)
-    i_pre = i_ref[0, 0].astype(jnp.float32)             # (L,)
-    f_log = jax.nn.log_sigmoid(f_ref[0, 0].astype(jnp.float32))
+    i_pre = i_ref[0, ic].astype(jnp.float32)            # (L,)
+    f_log = jax.nn.log_sigmoid(f_ref[0, ic].astype(jnp.float32))
 
     tril = jnp.tril(jnp.ones((L, L), jnp.float32))      # includes diagonal
     F = jnp.dot(tril, f_log[:, None])[:, 0]             # inclusive cumsum (L,)
@@ -85,8 +83,8 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, c0_ref, n0_ref, m0_ref,
     @pl.when(ic == nc - 1)
     def _emit_state():
         cN_ref[0] = C_ref[...]
-        nN_ref[0] = n_ref[0]
-        mN_ref[0] = m_ref[0]
+        nN_ref[0] = n_ref[...]
+        mN_ref[0] = m_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -109,7 +107,10 @@ def mlstm_chunked_kernel(q, k, v, i_pre, f_pre, state=None, *, chunk=256,
         m0 = jnp.full((BH, 1), -1e30, jnp.float32)
     else:
         C0, n0, m0 = state
-        m0 = m0.reshape(BH, 1)
+    # per-row state as (1, x) tiles: a (1, x) block of a (BH, x) array
+    # would break the TPU's (8, 128) tiling
+    n0 = n0.reshape(BH, 1, dk)
+    m0 = m0.reshape(BH, 1, 1)
 
     kernel = functools.partial(_mlstm_kernel, L=L, scale=1.0 / np.sqrt(dk),
                                nc=nc)
@@ -120,33 +121,35 @@ def mlstm_chunked_kernel(q, k, v, i_pre, f_pre, state=None, *, chunk=256,
             pl.BlockSpec((1, 1, L, dk), lambda bh, ic: (bh, ic, 0, 0)),
             pl.BlockSpec((1, 1, L, dk), lambda bh, ic: (bh, ic, 0, 0)),
             pl.BlockSpec((1, 1, L, dv), lambda bh, ic: (bh, ic, 0, 0)),
-            pl.BlockSpec((1, 1, L), lambda bh, ic: (bh, ic, 0)),
-            pl.BlockSpec((1, 1, L), lambda bh, ic: (bh, ic, 0)),
+            # gates: every chunk of a row at once — a (1, L) tile of the
+            # (nc, L) gate matrix would break the TPU's (8, 128) tiling
+            pl.BlockSpec((1, nc, L), lambda bh, ic: (bh, 0, 0)),
+            pl.BlockSpec((1, nc, L), lambda bh, ic: (bh, 0, 0)),
             pl.BlockSpec((1, dk, dv), lambda bh, ic: (bh, 0, 0)),
-            pl.BlockSpec((1, dk), lambda bh, ic: (bh, 0)),
-            pl.BlockSpec((1, 1), lambda bh, ic: (bh, 0)),
+            pl.BlockSpec((1, 1, dk), lambda bh, ic: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda bh, ic: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, L, dv), lambda bh, ic: (bh, ic, 0, 0)),
             pl.BlockSpec((1, dk, dv), lambda bh, ic: (bh, 0, 0)),
-            pl.BlockSpec((1, dk), lambda bh, ic: (bh, 0)),
-            pl.BlockSpec((1, 1), lambda bh, ic: (bh, 0)),
+            pl.BlockSpec((1, 1, dk), lambda bh, ic: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda bh, ic: (bh, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, nc, L, dv), v.dtype),
             jax.ShapeDtypeStruct((BH, dk, dv), jnp.float32),
-            jax.ShapeDtypeStruct((BH, dk), jnp.float32),
-            jax.ShapeDtypeStruct((BH, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, dk), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((dk, dv), jnp.float32),
             pltpu.VMEM((1, dk), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="ham_mlstm_chunked",
     )(qs, ks_, vs, is_, fs, C0, n0, m0)
-    return h.reshape(BH, S, dv), (cN, nN, mN.reshape(BH))
+    return h.reshape(BH, S, dv), (cN, nN.reshape(BH, dk), mN.reshape(BH))

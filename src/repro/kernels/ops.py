@@ -1,8 +1,8 @@
 """jit'd public wrappers over the Pallas kernels.
 
-Backend policy: on TPU the compiled kernels run natively; elsewhere (this
-CPU container, unit tests) they run in ``interpret=True`` mode so the exact
-kernel bodies are validated against the ``ref.py`` oracles.  The model code
+The kernels compile for the TPU.  Nothing falls back: a caller without a
+TPU (the unit tests, on the CPU) passes ``interpret=True`` itself, which
+runs the exact kernel bodies against the ``ref.py`` oracles.  The model code
 selects kernels via ``ModelConfig.attn_impl`` — the XLA reference path stays
 the default for the dry-run (kernels are opaque custom-calls to
 ``cost_analysis``, which would blind the roofline).
@@ -10,7 +10,6 @@ the default for the dry-run (kernels are opaque custom-calls to
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
@@ -21,13 +20,8 @@ from repro.kernels.mamba2_ssd import ssd_chunked_kernel as _ssd
 from repro.kernels.mlstm import mlstm_chunked_kernel as _mlstm
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def flash_attention_bhsd(q, k, v, *, causal=True, interpret=None):
+def flash_attention_bhsd(q, k, v, *, causal=True, interpret=False):
     """Model-layout wrapper: q (B, S, H, hd); k/v (B, S, Hkv, hd)."""
-    interpret = _interpret_default() if interpret is None else interpret
     B, S, H, hd = q.shape
     Hkv = k.shape[2]
     qpk = H // Hkv
@@ -38,9 +32,8 @@ def flash_attention_bhsd(q, k, v, *, causal=True, interpret=None):
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
 
 
-def decode_attention_bhsd(q, k, v, lengths, *, interpret=None):
+def decode_attention_bhsd(q, k, v, lengths, *, interpret=False):
     """q (B, 1, H, hd); k/v caches (B, S, Hkv, hd); lengths (B,)."""
-    interpret = _interpret_default() if interpret is None else interpret
     B, _, H, hd = q.shape
     Hkv = k.shape[2]
     qpk = H // Hkv
@@ -52,9 +45,8 @@ def decode_attention_bhsd(q, k, v, lengths, *, interpret=None):
 
 
 def mlstm_chunked(q, k, v, i_pre, f_pre, state=None, *, chunk=256,
-                  interpret=None):
+                  interpret=False):
     """Model layout: q,k (B, S, H, dk); v (B, S, H, dv); gates (B, S, H)."""
-    interpret = _interpret_default() if interpret is None else interpret
     B, S, H, dk = q.shape
     dv = v.shape[-1]
     fl = lambda a, last: a.transpose(0, 2, 1, 3).reshape(B * H, S, last)
@@ -71,9 +63,8 @@ def mlstm_chunked(q, k, v, i_pre, f_pre, state=None, *, chunk=256,
 
 
 def ssd_chunked(x, dt, A, Bm, Cm, D, state=None, *, chunk=256,
-                interpret=None):
+                interpret=False):
     """Model layout: x (B,S,H,P); dt (B,S,H); A (H,); Bm/Cm (B,S,G,N)."""
-    interpret = _interpret_default() if interpret is None else interpret
     B, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     hpg = H // G
@@ -89,8 +80,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, D, state=None, *, chunk=256,
     return y.astype(x.dtype), hN.reshape(B, H, N, P)
 
 
-def grouped_matmul(x, w, *, interpret=None, **blocks):
-    interpret = _interpret_default() if interpret is None else interpret
+def grouped_matmul(x, w, *, interpret=False, **blocks):
     return _gmm(x, w, interpret=interpret, **blocks)
 
 

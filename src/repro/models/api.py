@@ -91,7 +91,7 @@ def build_model(cfg: ModelConfig) -> Model:
 
         return Model(
             cfg=cfg,
-            init=lambda key: T.lm_init(key, cfg),
+            init=jax.jit(lambda key: T.lm_init(key, cfg)),
             loss=_generic_loss(fwd),
             forward=lambda p, b, sharder=None: fwd(p, b, sharder)[0],
             prefill=prefill,

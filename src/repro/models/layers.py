@@ -29,7 +29,7 @@ def _cast(x, dtype):
 
 
 def dense_init(key, in_dim, out_dim, dtype, *, bias=False, scale=None):
-    scale = scale if scale is not None else 1.0 / np.sqrt(in_dim)
+    scale = scale if scale is not None else in_dim**-0.5
     p = {"w": jax.random.normal(key, (in_dim, out_dim), dtype) * scale}
     if bias:
         p["b"] = jnp.zeros((out_dim,), dtype)
@@ -111,12 +111,12 @@ class AttnParamsSpec:
 def attention_init(key, spec: AttnParamsSpec, dtype):
     ks = jax.random.split(key, 4)
     d, h, hk, hd = spec.d_model, spec.num_heads, spec.num_kv_heads, spec.head_dim
-    s = 1.0 / np.sqrt(d)
+    s = d**-0.5
     p = {
         "wq": jax.random.normal(ks[0], (d, h, hd), dtype) * s,
         "wk": jax.random.normal(ks[1], (d, hk, hd), dtype) * s,
         "wv": jax.random.normal(ks[2], (d, hk, hd), dtype) * s,
-        "wo": jax.random.normal(ks[3], (h, hd, d), dtype) * (1.0 / np.sqrt(h * hd)),
+        "wo": jax.random.normal(ks[3], (h, hd, d), dtype) * (h * hd) ** -0.5,
     }
     if spec.qkv_bias:
         p["bq"] = jnp.zeros((h, hd), dtype)
@@ -362,8 +362,8 @@ def attention_apply(
 
 def mlp_init(key, d_model, d_ff, kind, dtype):
     ks = jax.random.split(key, 3)
-    s_in = 1.0 / np.sqrt(d_model)
-    s_out = 1.0 / np.sqrt(d_ff)
+    s_in = d_model**-0.5
+    s_out = d_ff**-0.5
     if kind == "swiglu":
         return {
             "w_gate": jax.random.normal(ks[0], (d_model, d_ff), dtype) * s_in,

@@ -141,7 +141,7 @@ def mamba2_block_init(key, cfg: ModelConfig):
         "ln": L.rmsnorm_init(d, dt_),
         # in_proj emits [z, x, B, C, dt]
         "w_in": jax.random.normal(ks[0], (d, 2 * di + 2 * G * N + H), dt_)
-        * (1.0 / np.sqrt(d)),
+        * d**-0.5,
         "conv": causal_conv_init(ks[1], s.conv_width, conv_ch, dt_),
         "A_log": jnp.log(jnp.linspace(1.0, 16.0, H).astype(jnp.float32)),
         "dt_bias": jnp.log(jnp.expm1(
@@ -150,7 +150,7 @@ def mamba2_block_init(key, cfg: ModelConfig):
         )),
         "D": jnp.ones((H,), jnp.float32),
         "out_norm": L.rmsnorm_init(di, dt_),
-        "w_out": jax.random.normal(ks[3], (di, d), dt_) * (1.0 / np.sqrt(di)),
+        "w_out": jax.random.normal(ks[3], (di, d), dt_) * di**-0.5,
     }
 
 

@@ -32,8 +32,8 @@ from repro.models.config import MoEConfig
 def moe_init(key, d_model: int, cfg: MoEConfig, dtype):
     ks = jax.random.split(key, 6)
     E, f = cfg.num_experts, cfg.d_ff_expert
-    s_in = 1.0 / np.sqrt(d_model)
-    s_out = 1.0 / np.sqrt(f)
+    s_in = d_model**-0.5
+    s_out = f**-0.5
     p = {
         "router": jax.random.normal(ks[0], (d_model, E), jnp.float32) * s_in,
         "w_gate": jax.random.normal(ks[1], (E, d_model, f), dtype) * s_in,

@@ -74,12 +74,13 @@ def layer_apply(p, x, cfg: ModelConfig, *, positions, sharder=None,
 
 
 def lm_init(key, cfg: ModelConfig):
+    """Weights with the layers stacked on a leading axis.  The layers are
+    drawn under ``vmap``, which writes the stacked arrays directly: no
+    per-layer copy is ever live beside them (run it under ``jax.jit`` —
+    ``build_model`` does — so the init is one program, not one per op)."""
     keys = jax.random.split(key, cfg.num_layers + 3)
     dt = jnp.dtype(cfg.param_dtype)
-    stacked = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs),
-        *[layer_init(keys[i], cfg) for i in range(cfg.num_layers)],
-    )
+    stacked = jax.vmap(lambda k: layer_init(k, cfg))(keys[: cfg.num_layers])
     p = {
         "embed": L.embedding_init(keys[-1], cfg.vocab_size, cfg.d_model, dt),
         "layers": stacked,
@@ -227,8 +228,24 @@ def lm_decode_step(p, cache, batch, cfg: ModelConfig, *, sharder=None,
         return (x, aux + a), new_cache_l
 
     if cfg.scan_layers:
-        (x, _), new_cache = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), (p["layers"], cache)
+        # the cache rides in the carry and each layer writes its slice back
+        # in place: emitted as scan outputs instead, the new cache would be
+        # a second whole copy of the cache beside the donated one
+        def carried(carry, layer_in):
+            x, aux, cache = carry
+            layer_p, i = layer_in
+            cache_l = jax.tree_util.tree_map(
+                lambda c: jax.lax.dynamic_index_in_dim(c, i, keepdims=False),
+                cache)
+            (x, aux), new_l = body((x, aux), (layer_p, cache_l))
+            cache = jax.tree_util.tree_map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+                cache, new_l)
+            return (x, aux, cache), None
+
+        (x, _, new_cache), _ = jax.lax.scan(
+            carried, (x, jnp.zeros((), jnp.float32), cache),
+            (p["layers"], jnp.arange(cfg.num_layers)),
         )
     else:
         outs = []

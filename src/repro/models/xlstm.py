@@ -33,7 +33,7 @@ from repro.models.config import ModelConfig
 
 
 def causal_conv_init(key, width, channels, dtype):
-    return {"w": jax.random.normal(key, (width, channels), dtype) * (1.0 / np.sqrt(width))}
+    return {"w": jax.random.normal(key, (width, channels), dtype) * width**-0.5}
 
 
 def causal_conv(p, x, dtype):
@@ -196,8 +196,8 @@ def mlstm_block_init(key, cfg: ModelConfig):
     H = cfg.num_heads
     ks = jax.random.split(key, 8)
     dt = jnp.dtype(cfg.param_dtype)
-    s = 1.0 / np.sqrt(d)
-    si = 1.0 / np.sqrt(di)
+    s = d**-0.5
+    si = di**-0.5
     return {
         "ln": L.rmsnorm_init(d, dt),
         "w_up": jax.random.normal(ks[0], (d, 2 * di), dt) * s,
@@ -303,13 +303,13 @@ def slstm_block_init(key, cfg: ModelConfig):
     dh = d // H
     ks = jax.random.split(key, 8)
     dt = jnp.dtype(cfg.param_dtype)
-    s = 1.0 / np.sqrt(d)
+    s = d**-0.5
     ffs = int(4 * d / 3)
     return {
         "ln": L.rmsnorm_init(d, dt),
         "conv": causal_conv_init(ks[0], cfg.xlstm.conv_width, d, dt),
         "w_gates": jax.random.normal(ks[1], (d, 4 * d), dt) * s,   # i,f,z,o
-        "r_gates": jax.random.normal(ks[2], (4, H, dh, dh), dt) * (1.0 / np.sqrt(dh)),
+        "r_gates": jax.random.normal(ks[2], (4, H, dh, dh), dt) * dh**-0.5,
         "b_gates": jnp.concatenate([
             jnp.zeros((d,), dt),
             jnp.full((d,), 3.0, dt),            # forget bias
@@ -317,7 +317,7 @@ def slstm_block_init(key, cfg: ModelConfig):
         ]),
         "out_norm": L.rmsnorm_init(d, dt),
         "w_up": jax.random.normal(ks[3], (d, 2 * ffs), dt) * s,     # GeGLU
-        "w_down": jax.random.normal(ks[4], (ffs, d), dt) * (1.0 / np.sqrt(ffs)),
+        "w_down": jax.random.normal(ks[4], (ffs, d), dt) * ffs**-0.5,
     }
 
 

@@ -157,6 +157,9 @@ def _spawn_worker_subprocess(spec: dict):
     env = dict(os.environ)
     root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    # no worker hosts a serving replica: one that touched JAX must not
+    # contend for the accelerator its parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [sys.executable, "-m", "repro.offload.worker", json.dumps(spec)], env=env
     )

@@ -38,8 +38,10 @@ class Request:
     deadline: float | None = None
 
 
-def build_serve_table(model, params, *, sharder=None, window=None):
-    """Device handler table over decode-step behaviours."""
+def build_serve_table(model, *, sharder=None):
+    """Device handler table over decode-step behaviours.  Every branch is
+    ``fn(payload, params)``: the weights arrive as a table operand, never
+    through a closure (a closed-over array becomes an executable constant)."""
     table = DeviceHandlerTable()
 
     def _next_from_logits(logits, payload, sample: bool):
@@ -53,27 +55,23 @@ def build_serve_table(model, params, *, sharder=None, window=None):
             nxt = greedy
         return nxt.astype(jnp.int32)[:, None], rng
 
-    def decode_greedy(payload):
+    def decode(payload, params, sample: bool):
         logits, cache = model.decode_step(
             params, payload["cache"],
             {"tokens": payload["tokens"], "pos": payload["pos"]},
             sharder=sharder,
         )
-        nxt, rng = _next_from_logits(logits, payload, sample=False)
+        nxt, rng = _next_from_logits(logits, payload, sample=sample)
         return {"cache": cache, "tokens": nxt, "pos": payload["pos"] + 1,
                 "rng": rng, "temp": payload["temp"]}
 
-    def decode_sample(payload):
-        logits, cache = model.decode_step(
-            params, payload["cache"],
-            {"tokens": payload["tokens"], "pos": payload["pos"]},
-            sharder=sharder,
-        )
-        nxt, rng = _next_from_logits(payload=payload, logits=logits, sample=True)
-        return {"cache": cache, "tokens": nxt, "pos": payload["pos"] + 1,
-                "rng": rng, "temp": payload["temp"]}
+    def decode_greedy(payload, params):
+        return decode(payload, params, sample=False)
 
-    def noop(payload):
+    def decode_sample(payload, params):
+        return decode(payload, params, sample=True)
+
+    def noop(payload, params):
         # bubble/straggler filler: burns a step slot without touching state
         return dict(payload)
 
@@ -84,42 +82,70 @@ def build_serve_table(model, params, *, sharder=None, window=None):
     return table
 
 
-class ServingEngine:
-    """Continuous-batching loop on top of the compiled dispatch table."""
+def _spec(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree
+    )
 
-    def __init__(self, model, params, *, num_slots: int, max_len: int,
-                 sharder=None, seed: int = 0, donate: bool = True):
+
+class ServeProgram:
+    """The compiled half of a serving replica: the decode dispatch table,
+    fused multi-step decode blocks and fused admission, each jitted once.
+
+    The weights enter every executable as an argument — not donated, not
+    captured — so the executables hold no weights, one program serves every
+    replica of a model (they share its compilations), and the replicas on a
+    device share one copy of the weights.  The payload (KV cache, slot
+    tokens and positions, rng, temperature) is donated: its update is in
+    place on the device.
+    """
+
+    def __init__(self, model, params_spec, *, num_slots: int, max_len: int,
+                 sharder=None):
         self.model = model
-        self.params = params
         self.B = num_slots
         self.max_len = max_len
-        self.table = build_serve_table(model, params, sharder=sharder)
-        cache = model.init_cache(num_slots, max_len)
-        self.payload = {
-            "cache": cache,
-            "tokens": jnp.zeros((num_slots, 1), jnp.int32),
-            "pos": jnp.zeros((num_slots,), jnp.int32),
-            "rng": jax.random.PRNGKey(seed),
-            "temp": jnp.zeros((), jnp.float32),
-        }
-        spec = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.payload
+        self.table = build_serve_table(model, sharder=sharder)
+        self.payload_spec = jax.eval_shape(lambda: self.init_payload(0))
+        self.dispatch = self.table.build(
+            self.payload_spec, params_spec, donate_payload=True
         )
-        self.dispatch = self.table.build(spec, donate_payload=donate)
-        # un-jitted dispatch, scanned by step_many (fused multi-step blocks)
-        self._dispatch_raw = self.table.build(spec, jit=False)
         self._multi_fns: dict[int, Any] = {}
         self.key_greedy = self.table.key_of("serve/decode_greedy")
         self.key_sample = self.table.key_of("serve/decode_sample")
         self.key_noop = self.table.key_of("serve/noop")
-        self._admit_fused = self._build_admit_fused(sharder)
-        # slot bookkeeping (host side)
-        self.slot_req: list[Request | None] = [None] * num_slots
-        self.slot_remaining = np.zeros(num_slots, np.int64)
-        self.outputs: dict[int, list[int]] = {}
-        self.steps_dispatched = 0
+        self.admit = self._build_admit_fused(sharder)
 
-    # -- slot admission ----------------------------------------------------------
+    def init_payload(self, seed: int) -> dict:
+        return {
+            "cache": self.model.init_cache(self.B, self.max_len),
+            "tokens": jnp.zeros((self.B, 1), jnp.int32),
+            "pos": jnp.zeros((self.B,), jnp.int32),
+            "rng": jax.random.PRNGKey(seed),
+            "temp": jnp.zeros((), jnp.float32),
+        }
+
+    def multi(self, k: int):
+        """``multi(payload, params) -> (payload, tokens (k, B))``: ``k``
+        greedy decode steps fused into one executable, a ``lax.scan`` over
+        the table's greedy handler.  The block is greedy only, so it calls
+        that handler itself: a ``lax.switch`` inside the scan would make
+        XLA copy the whole KV cache around every step's conditional (for a
+        qwen1.5-4b replica of 4 slots x 512 positions, 2.0 GB of
+        temporaries instead of 1.0 GB, compiled for a TPU v5e)."""
+        fn = self._multi_fns.get(k)
+        if fn is None:
+            greedy = self.table.handlers[self.key_greedy].fn
+
+            def multi(payload, params):
+                def body(p, _):
+                    p2 = greedy(p, params)
+                    return p2, p2["tokens"][:, 0]
+
+                return jax.lax.scan(body, payload, None, length=k)
+
+            fn = self._multi_fns[k] = jax.jit(multi, donate_argnums=(0,))
+        return fn
 
     def _build_admit_fused(self, sharder):
         """Compile the whole admission — prefill, batch-cache insert, slot
@@ -163,12 +189,53 @@ class ServingEngine:
 
         return jax.jit(admit_fused, donate_argnums=(1, 2, 3))
 
+
+def _single_device(params):
+    """The one device holding ``params``, or None when they are sharded."""
+    devices = jax.tree_util.tree_leaves(params)[0].devices()
+    return next(iter(devices)) if len(devices) == 1 else None
+
+
+class ServingEngine:
+    """Continuous-batching loop on top of the compiled dispatch table.
+
+    The replica lives on the device that holds ``params``: its payload is
+    created there, and every executable runs there.  ``program`` shares one
+    :class:`ServeProgram` (and so its compilations) between replicas.
+    """
+
+    def __init__(self, model, params, *, num_slots: int, max_len: int,
+                 sharder=None, seed: int = 0,
+                 program: ServeProgram | None = None):
+        if program is None:
+            program = ServeProgram(model, _spec(params), num_slots=num_slots,
+                                   max_len=max_len, sharder=sharder)
+        self.model = model
+        self.params = params
+        self.program = program
+        self.B = program.B
+        self.max_len = program.max_len
+        self.table = program.table
+        self.key_greedy = program.key_greedy
+        self.key_sample = program.key_sample
+        self.key_noop = program.key_noop
+        self.device = _single_device(params)
+        with jax.default_device(self.device):
+            self.payload = jax.device_put(program.init_payload(seed),
+                                          self.device)
+        # slot bookkeeping (host side)
+        self.slot_req: list[Request | None] = [None] * self.B
+        self.slot_remaining = np.zeros(self.B, np.int64)
+        self.outputs: dict[int, list[int]] = {}
+        self.steps_dispatched = 0
+
+    # -- slot admission ----------------------------------------------------------
+
     def admit(self, req: Request, slot: int) -> None:
         prompt = np.asarray(req.prompt, np.int32)[None, :]  # (1, S)
-        cache, tokens, pos, first = self._admit_fused(
+        cache, tokens, pos, first = self.program.admit(
             self.params, self.payload["cache"], self.payload["tokens"],
-            self.payload["pos"], jnp.asarray(prompt),
-            jnp.asarray(slot, jnp.int32),
+            self.payload["pos"], prompt, np.int32(slot),
         )
         self.payload["cache"] = cache
         self.payload["tokens"] = tokens
@@ -216,8 +283,9 @@ class ServingEngine:
                 key = self.key_greedy
         temps = max((r.temperature for r in self.slot_req if r is not None),
                     default=0.0)
-        self.payload["temp"] = jnp.asarray(temps, jnp.float32)
-        self.payload = self.dispatch(jnp.asarray(key, jnp.int32), self.payload)
+        self.payload["temp"] = jax.device_put(np.float32(temps), self.device)
+        self.payload = self.program.dispatch(np.int32(key), self.payload,
+                                             self.params)
         self.steps_dispatched += 1
         if key == self.key_noop:
             return []
@@ -233,21 +301,9 @@ class ServingEngine:
                 self.slot_req[slot] = None
         return emitted
 
-    def _multi_dispatch(self, k: int):
-        raw = self._dispatch_raw
-
-        def multi(key, payload):
-            def body(p, _):
-                p2 = raw(key, p)
-                return p2, p2["tokens"][:, 0]
-
-            return jax.lax.scan(body, payload, None, length=k)
-
-        return jax.jit(multi, donate_argnums=(1,))
-
     def step_many(self, k: int) -> list[tuple[int, int]]:
         """Up to ``k`` decode steps fused into ONE device dispatch: a
-        ``lax.scan`` over the same compiled handler table, returning the
+        ``lax.scan`` over the table's greedy handler, returning the
         stacked per-step tokens in a single host transfer.
 
         This is the worker-driven loop's amortisation lever: the per-step
@@ -275,13 +331,8 @@ class ServingEngine:
                 if all(r is None for r in self.slot_req):
                     break
             return out
-        fn = self._multi_fns.get(k)
-        if fn is None:
-            fn = self._multi_fns[k] = self._multi_dispatch(k)
-        self.payload["temp"] = jnp.asarray(0.0, jnp.float32)
-        self.payload, toks = fn(
-            jnp.asarray(self.key_greedy, jnp.int32), self.payload
-        )
+        self.payload["temp"] = jax.device_put(np.float32(0.0), self.device)
+        self.payload, toks = self.program.multi(k)(self.payload, self.params)
         self.steps_dispatched += k
         toks_np = np.asarray(toks)  # (k, B)
         emitted: list[tuple[int, int]] = []
@@ -334,8 +385,12 @@ from repro.serve.handlers import (  # noqa: E402,F401
 class ClusterServingEngine:
     """Continuous batching sharded across a worker pool.
 
-    One :class:`ServingEngine` replica per pool worker (thread workers —
-    the replicas share the process and its jax devices).  Two drive modes:
+    One :class:`ServingEngine` replica per pool worker (thread workers in
+    this process).  The replicas share one :class:`ServeProgram`, so a
+    shape compiles once per device.  With ``devices``, each new replica goes
+    to the listed device holding the fewest replicas, with one copy of the
+    weights per device; without, every replica serves from ``params`` where
+    they lie.  Two drive modes:
 
     **Worker-driven** (default, the production path — docs/serving.md):
     each replica gets a :class:`~repro.serve.stream.WorkerDecodeLoop` that
@@ -385,7 +440,8 @@ class ClusterServingEngine:
     def __init__(self, model, params, *, num_workers: int = 2,
                  slots_per_worker: int = 2, max_len: int, seed: int = 0,
                  registry=None, worker_driven: bool = True,
-                 admission_limit: int | None = None, decode_block: int = 16):
+                 admission_limit: int | None = None, decode_block: int = 16,
+                 devices=None):
         import threading
 
         from repro.cluster.pool import ClusterPool, register_cluster_handlers
@@ -408,7 +464,13 @@ class ClusterServingEngine:
         #: decode steps each worker loop fuses per iteration (step_many)
         self.decode_block = max(1, int(decode_block))
         self._model, self._params = model, params
-        self._max_len, self._seed = max_len, seed
+        self._seed = seed
+        self._program = ServeProgram(model, _spec(params),
+                                     num_slots=slots_per_worker,
+                                     max_len=max_len)
+        self._devices = list(devices) if devices is not None else None
+        self._device_params: dict = {}          # device -> weights there
+        self._replica_device: dict[int, Any] = {}   # node -> device
         self.pool = ClusterPool.local(num_workers, registry=registry)
         self.sched = Scheduler(self.pool, policy="least_outstanding",
                                max_inflight=slots_per_worker + 2)
@@ -454,8 +516,10 @@ class ClusterServingEngine:
             return  # non-local worker modes build engines worker-side
         self._drop_replica(node)  # a restarted node gets a fresh engine
         eng = ServingEngine(
-            self._model, self._params, num_slots=self.slots_per_worker,
-            max_len=self._max_len, seed=self._seed + node,
+            self._model, self._replica_params(node),
+            num_slots=self.slots_per_worker,
+            max_len=self._program.max_len, seed=self._seed + node,
+            program=self._program,
         )
         _NODE_ENGINES[id(rt)] = eng
         if self.worker_driven:
@@ -470,7 +534,27 @@ class ClusterServingEngine:
         with self._wd:
             self._wd.notify_all()  # fresh capacity for the admission pump
 
+    def _replica_params(self, node: int):
+        """The weights for ``node``'s replica, on the device it is placed
+        on (least-loaded of ``devices``); one copy per device."""
+        if self._devices is None:
+            return self._params
+        load = {d: 0 for d in self._devices}
+        for d in self._replica_device.values():
+            load[d] += 1
+        dev = min(self._devices, key=lambda d: load[d])
+        self._replica_device[node] = dev
+        if dev not in self._device_params:
+            self._device_params[dev] = jax.device_put(self._params, dev)
+        return self._device_params[dev]
+
+    def replica_devices(self) -> dict[int, Any]:
+        """node -> device of every live replica's weights and cache."""
+        return {n: _NODE_ENGINES[k].device
+                for n, k in self._engine_keys.items() if k in _NODE_ENGINES}
+
     def _drop_replica(self, node: int) -> None:
+        self._replica_device.pop(node, None)
         key = self._engine_keys.pop(node, None)
         if key is not None:
             loop = _NODE_LOOPS.pop(key, None)
@@ -538,7 +622,7 @@ class ClusterServingEngine:
 
     def _apply_stream_locked(self, node: int, rid: int, gen: int, seq: int,
                              token: int, status: int, now: float) -> None:
-        from repro.core.flags import STREAM_DONE, STREAM_TOKEN
+        from repro.core.flags import STREAM_DONE, STREAM_FAILED, STREAM_TOKEN
 
         if self._gen.get(rid) != gen or rid in self._done:
             return  # stale generation (pre-recovery straggler) or late
@@ -565,7 +649,21 @@ class ClusterServingEngine:
         ):
             self._finalize_locked(rid, STREAM_DONE, now)
         elif status not in (STREAM_TOKEN, STREAM_DONE):
+            if status == STREAM_FAILED:
+                self._errors[rid] = self._loop_failure(node)
             self._finalize_locked(rid, status, now)
+
+    def _loop_failure(self, node: int) -> Exception:
+        """The error that stopped ``node``'s decode loop, as the host's
+        per-request error (the loop is in this process: replicas are
+        thread workers)."""
+        from repro.core.errors import OffloadError
+
+        loop = _NODE_LOOPS.get(self._engine_keys.get(node))
+        cause = getattr(loop, "error", None)
+        err = OffloadError(f"decode loop on worker {node} failed: {cause!r}")
+        err.__cause__ = cause
+        return err
 
     def _finalize_locked(self, rid: int, status: int, now: float) -> None:
         self._done[rid] = status
@@ -900,12 +998,16 @@ class ClusterServingEngine:
             for rid in sorted(target & self._errors.keys()):
                 raise self._errors[rid]
 
+    def transcripts(self, rids) -> dict[int, list[int]]:
+        """The tokens streamed so far for each of ``rids``."""
+        with self._wd:
+            return {rid: list(self._transcripts.get(rid, ())) for rid in rids}
+
     def _run_worker_driven(self, requests: list[Request],
                            timeout: float) -> dict[int, list[int]]:
         rids = [self.submit_request(r, shed=False) for r in requests]
         self.wait(rids, timeout=timeout)
-        with self._wd:
-            out = {rid: list(self._transcripts.get(rid, ())) for rid in rids}
+        out = self.transcripts(rids)
         for rid in rids:  # idempotent with the pump's session teardown
             self.sched.end_session(f"serve/{rid}")
         return out

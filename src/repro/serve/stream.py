@@ -7,7 +7,7 @@ and hands over the prompt; from then on this loop steps the worker's
 involvement** — requests join and leave the running batch at block
 boundaries, and tokens travel back as oneways.  Each loop iteration runs
 one *fused decode block* (``engine.step_many``: a ``lax.scan`` over the
-device handler table, amortising per-dispatch overhead across ``block``
+device table's greedy handler, amortising per-dispatch overhead across ``block``
 steps), then ships each request's block of tokens as ONE
 ``_serve/stream_block`` segment (single-token messages and end-of-stream
 acks ride ``_serve/stream``).  All segments produced by one iteration are
@@ -30,7 +30,11 @@ Delivery/ordering contract (asserted by the stream tests):
   dead worker's loop carry a stale ``gen`` and are dropped on arrival;
 * cancel/expiry acks are unconditional — a cancel for a request this loop
   has never seen (e.g. the admit died in flight) still acks, so the host
-  never waits on a tombstone.
+  never waits on a tombstone;
+* failures end requests — when the engine raises (a compile, device or
+  out-of-memory error), the loop stops and every live and queued request
+  ends with ``STREAM_FAILED`` at once, so the host raises the error instead
+  of waiting out its timeout.
 
 This module is jax-free at import time (the engine object is injected);
 only nodes that actually host a replica pay for the jax stack.
@@ -38,6 +42,7 @@ only nodes that actually host a replica pay for the jax stack.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -47,6 +52,8 @@ import numpy as np
 from repro.core.flags import (
     STREAM_CANCELLED,
     STREAM_DONE,
+    STREAM_EXPIRED,
+    STREAM_FAILED,
     STREAM_TOKEN,
 )
 
@@ -87,6 +94,8 @@ class WorkerDecodeLoop:
         #: rid -> {gen, seq, remaining, expires} for requests in the batch
         self._live: dict[int, dict] = {}
         self._stop = False
+        #: the exception that stopped this loop (None while it runs)
+        self.error: Exception | None = None
         self.stats = {"steps": 0, "tokens": 0, "frames": 0, "parks": 0,
                       "expired": 0, "cancelled": 0}
         self._thread = threading.Thread(
@@ -103,7 +112,10 @@ class WorkerDecodeLoop:
             if self._stop:
                 from repro.core.errors import OffloadError
 
-                raise OffloadError("decode loop is stopped on this worker")
+                why = f": {self.error!r}" if self.error is not None else ""
+                raise OffloadError(
+                    f"decode loop is stopped on this worker{why}"
+                ) from self.error
             self._admits.append((prompt, rid, gen, max_new_tokens,
                                  temperature, deadline_s))
             self._cv.notify()
@@ -190,83 +202,113 @@ class WorkerDecodeLoop:
             for rid in [r for r, lv in self._live.items()
                         if lv["expires"] is not None
                         and now >= lv["expires"]]:
-                from repro.core.flags import STREAM_EXPIRED
-
                 self.stats["expired"] += 1
                 self._finish(f2f, rid, STREAM_EXPIRED, calls)
-            # 2. admissions into freed slots (prefill runs HERE, on the
-            # worker, overlapping other replicas' decode steps)
-            for i, (prompt, rid, gen, max_new, temp,
-                    deadline_s) in enumerate(admits):
-                if (rid, gen) in self._tombstones:
-                    calls.append(self._stream_call(f2f, rid, gen, 0, -1,
-                                                   STREAM_CANCELLED))
-                    continue
-                from repro.serve.engine import Request
+            try:
+                self._admit(f2f, admits, now, calls)
+                self._decode_block(f2f, calls)
+            except Exception as exc:  # noqa: BLE001 — fail requests, not hang
+                self._fail(f2f, exc, admits, calls)
+                return
+            if calls:
+                self._flush(calls)
 
-                free_now = eng.free_slots()
-                if not free_now:  # slots re-counted: defer the rest
-                    with self._cv:
-                        self._admits.extendleft(reversed(admits[i:]))
-                    break
-                slot = free_now[0]
-                eng.admit(Request(prompt=prompt, max_new_tokens=max_new,
-                                  temperature=temp, rid=rid), slot)
-                first = int(eng.outputs[rid][0])
-                live = {
+    def _admit(self, f2f, admits: list, now: float, calls: list) -> None:
+        """Admissions into freed slots (prefill runs HERE, on the worker,
+        overlapping other replicas' decode steps).  ``admits`` keeps the
+        ones not yet in the batch."""
+        from repro.serve.engine import Request
+
+        eng = self._eng
+        while admits:
+            prompt, rid, gen, max_new, temp, deadline_s = admits[0]
+            if (rid, gen) in self._tombstones:
+                admits.pop(0)
+                calls.append(self._stream_call(f2f, rid, gen, 0, -1,
+                                               STREAM_CANCELLED))
+                continue
+            free_now = eng.free_slots()
+            if not free_now:  # slots re-counted: defer the rest
+                with self._cv:
+                    self._admits.extendleft(reversed(admits))
+                admits.clear()
+                return
+            eng.admit(Request(prompt=prompt, max_new_tokens=max_new,
+                              temperature=temp, rid=rid), free_now[0])
+            admits.pop(0)
+            first = int(eng.outputs[rid][0])
+            if max_new <= 1:
+                # single-token lease: the prefill's argmax IS the whole
+                # request — free the slot without a decode step
+                eng.evict(rid)
+                self._tombstones.append((rid, gen))
+                status = STREAM_DONE
+            else:
+                self._live[rid] = {
                     "gen": gen, "seq": 1, "remaining": max_new - 1,
                     "expires": now + deadline_s if deadline_s > 0 else None,
                 }
-                if max_new <= 1:
-                    # single-token lease: the prefill's argmax IS the whole
-                    # request — free the slot without a decode step
-                    eng.evict(rid)
-                    self._tombstones.append((rid, gen))
-                    status = STREAM_DONE
-                else:
-                    self._live[rid] = live
-                    status = STREAM_TOKEN
-                self.stats["tokens"] += 1
-                calls.append(self._stream_call(f2f, rid, gen, 0, first,
-                                               status))
-            # 3. one fused block of batched decode steps ([] when empty):
-            # per-dispatch overhead amortised over the whole block
-            emitted = eng.step_many(self._block)
-            if emitted:
-                self.stats["steps"] += 1
-            # group each request's tokens (emitted is step-major, so the
-            # per-request order is already ascending) and ship ONE
-            # _serve/stream_block segment per request per block
-            by_rid: dict[int, list[int]] = {}
-            for rid, tok in emitted:
-                by_rid.setdefault(rid, []).append(int(tok))
-            from repro.serve.handlers import STREAM_BLOCK_MAX
+                status = STREAM_TOKEN
+            self.stats["tokens"] += 1
+            calls.append(self._stream_call(f2f, rid, gen, 0, first, status))
 
-            for rid, toks in by_rid.items():
-                live = self._live.get(rid)
-                if live is None:
-                    continue  # evicted mid-iteration
-                live["remaining"] -= len(toks)
-                done = live["remaining"] <= 0
-                self.stats["tokens"] += len(toks)
-                for i in range(0, len(toks), STREAM_BLOCK_MAX):
-                    chunk = toks[i : i + STREAM_BLOCK_MAX]
-                    last = i + len(chunk) >= len(toks)
-                    status = STREAM_DONE if (done and last) else STREAM_TOKEN
-                    calls.append(self._stream_block_call(
-                        f2f, rid, live["gen"], live["seq"], chunk, status))
-                    live["seq"] += len(chunk)
-                if done:
-                    self._live.pop(rid, None)
-                    self._tombstones.append((rid, live["gen"]))
-            if calls:
-                self._flush(calls)
+    def _decode_block(self, f2f, calls: list) -> None:
+        """One fused block of batched decode steps (none when the batch is
+        empty): per-dispatch overhead amortised over the whole block."""
+        from repro.serve.handlers import STREAM_BLOCK_MAX
+
+        emitted = self._eng.step_many(self._block)
+        if emitted:
+            self.stats["steps"] += 1
+        # group each request's tokens (emitted is step-major, so the
+        # per-request order is already ascending) and ship ONE
+        # _serve/stream_block segment per request per block
+        by_rid: dict[int, list[int]] = {}
+        for rid, tok in emitted:
+            by_rid.setdefault(rid, []).append(int(tok))
+        for rid, toks in by_rid.items():
+            live = self._live.get(rid)
+            if live is None:
+                continue  # evicted mid-iteration
+            live["remaining"] -= len(toks)
+            done = live["remaining"] <= 0
+            self.stats["tokens"] += len(toks)
+            for i in range(0, len(toks), STREAM_BLOCK_MAX):
+                chunk = toks[i : i + STREAM_BLOCK_MAX]
+                last = i + len(chunk) >= len(toks)
+                status = STREAM_DONE if (done and last) else STREAM_TOKEN
+                calls.append(self._stream_block_call(
+                    f2f, rid, live["gen"], live["seq"], chunk, status))
+                live["seq"] += len(chunk)
+            if done:
+                self._live.pop(rid, None)
+                self._tombstones.append((rid, live["gen"]))
+
+    def _fail(self, f2f, exc: Exception, admits: list, calls: list) -> None:
+        """The engine raised: its donated payload may be gone, so the loop
+        stops.  Every live and queued request ends with ``STREAM_FAILED``
+        now; :attr:`error` keeps the exception for the host to raise."""
+        logging.getLogger(__name__).error(
+            "%s failed", self._thread.name, exc_info=exc)
+        with self._cv:
+            self.error = exc
+            self._stop = True
+            admits = list(admits) + list(self._admits)
+            self._admits.clear()
+        for rid in list(self._live):
+            self._finish(f2f, rid, STREAM_FAILED, calls)
+        for _prompt, rid, gen, *_ in admits:
+            calls.append(self._stream_call(f2f, rid, gen, 0, -1,
+                                           STREAM_FAILED))
+        self._flush(calls)
 
     def _flush(self, calls: list) -> None:
         """Ship this iteration's stream calls as fused oneways: msg_id 0
         segments in FLAG_FUSED frames (one frame per FUSE_MAX_SEGMENTS)."""
         from repro.offload.runtime import FUSE_MAX_SEGMENTS
 
+        if self._rt._stop.is_set():
+            return  # the node is down: like a crashed worker, it sends nothing
         try:
             if len(calls) == 1:
                 self._rt.send_oneway(self._host, calls[0])

@@ -234,3 +234,41 @@ def test_noop_branch_preserves_state(model_and_params):
         if a.dtype == np.uint32:  # rng key unchanged by noop too
             pass
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _largest_constant(hlo_text: str) -> int:
+    """Element count of the largest ``stablehlo.constant`` in ``hlo_text``."""
+    import re
+
+    sizes = [0]
+    for dims in re.findall(r"stablehlo\.constant dense<.*?> : tensor<([^>]*)>",
+                           hlo_text):
+        n = 1
+        for d in dims.split("x")[:-1]:  # the last field is the dtype
+            n *= int(d)
+        sizes.append(n)
+    return max(sizes)
+
+
+def test_serve_executables_embed_no_weights(model_and_params):
+    """Weights enter every serve executable as an argument: a closed-over
+    array would be lowered into the executable as a constant, one copy of
+    the model per executable."""
+    model, params = model_and_params
+    eng = ServingEngine(model, params, num_slots=2, max_len=16)
+    prog, payload = eng.program, eng.payload
+    smallest_weight = min(a.size for a in jax.tree_util.tree_leaves(params)
+                          if a.ndim >= 2)
+    lowered = {
+        "decode_step": prog.dispatch.lower(np.int32(prog.key_greedy),
+                                           payload, params),
+        "decode_block": prog.multi(4).lower(payload, params),
+        "admission": prog.admit.lower(
+            params, payload["cache"], payload["tokens"], payload["pos"],
+            np.zeros((1, 5), np.int32), np.int32(0)),
+    }
+    for name, low in lowered.items():
+        text = low.as_text()
+        assert _largest_constant(text) < smallest_weight, name
+        # and the weights are parameters of the executable
+        assert f"tensor<{model.cfg.vocab_size}x{model.cfg.d_model}x" in text

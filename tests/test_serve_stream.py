@@ -157,8 +157,9 @@ def test_kill_mid_decode_replays_without_dup_or_loss(model_and_params):
         while time.time() < deadline:
             with eng._wd:
                 streamed = sum(len(t) for t in eng._transcripts.values())
-            if streamed >= 12:  # loops are live and mid-decode
-                victim = eng.serving_nodes()[0]
+                decoding = sorted(set(eng._placed.values()))
+            if streamed >= 12 and decoding:  # loops are live and mid-decode
+                victim = decoding[0]  # a worker holding live requests
                 eng.pool.kill(victim)
                 killed["node"] = victim
                 return
@@ -240,5 +241,37 @@ def test_deadline_expires_mid_decode(model_and_params):
         with eng._wd:
             assert eng._done[rid] == STREAM_EXPIRED
             assert 0 < len(eng._transcripts[rid]) < 400
+    finally:
+        eng.close()
+
+
+def test_decode_loop_failure_reaches_wait(model_and_params, monkeypatch):
+    """An error inside a worker's decode loop (a compile, device or
+    out-of-memory error on the chip) fails that loop's live and queued
+    requests at once: ``wait`` raises it within seconds instead of timing
+    out."""
+    from repro.core.errors import OffloadError
+    from repro.core.flags import STREAM_FAILED
+
+    model, params = model_and_params
+    cfg = model.cfg
+
+    def boom(self, k):
+        raise RuntimeError("boom: device step failed")
+
+    monkeypatch.setattr(ServingEngine, "step_many", boom)
+    eng = ClusterServingEngine(model, params, num_workers=1,
+                               slots_per_worker=2, max_len=32)
+    try:
+        # 2 requests fill the slots and fail live; 2 more are queued
+        rids = [eng.submit_request(r, shed=False) for r in _reqs(cfg, 4)]
+        t0 = time.monotonic()
+        with pytest.raises(OffloadError, match="boom") as info:
+            eng.wait(rids, timeout=60.0)
+        assert time.monotonic() - t0 < 10.0
+        assert isinstance(info.value.__cause__, RuntimeError)
+        with eng._wd:
+            assert set(rids) <= eng._done.keys()
+            assert eng._done[rids[0]] == STREAM_FAILED
     finally:
         eng.close()
