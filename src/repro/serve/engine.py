@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core.device_table import DeviceHandlerTable
 from repro.core.future import Future
+from repro.serve.spans import SpanLog, span
 
 
 @dataclasses.dataclass
@@ -139,7 +140,8 @@ class ServeProgram:
 
             def multi(payload, params):
                 def body(p, _):
-                    p2 = greedy(p, params)
+                    with jax.named_scope("decode_step"):
+                        p2 = greedy(p, params)
                     return p2, p2["tokens"][:, 0]
 
                 return jax.lax.scan(body, payload, None, length=k)
@@ -173,18 +175,23 @@ class ServeProgram:
             return jax.lax.dynamic_update_slice(full, part, tuple(starts))
 
         def admit_fused(params, cache, tokens, pos, prompt, slot):
-            logits, pcache = model.prefill(
-                params, {"tokens": prompt}, sharder=sharder
-            )
-            cache = jax.tree_util.tree_map(
-                lambda f, p: ins(f, p, slot), cache, pcache
-            )
-            first = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            tokens = jax.lax.dynamic_update_slice(tokens, first[:, None],
-                                                  (slot, 0))
-            pos = jax.lax.dynamic_update_slice(
-                pos, jnp.full((1,), prompt.shape[1], jnp.int32), (slot,)
-            )
+            # the scopes name each part in the profiler's op metadata
+            with jax.named_scope("prefill"):
+                logits, pcache = model.prefill(
+                    params, {"tokens": prompt}, sharder=sharder
+                )
+            with jax.named_scope("cache_insert"):
+                cache = jax.tree_util.tree_map(
+                    lambda f, p: ins(f, p, slot), cache, pcache
+                )
+            with jax.named_scope("first_token"):
+                first = jnp.argmax(logits[:, -1, :],
+                                   axis=-1).astype(jnp.int32)
+                tokens = jax.lax.dynamic_update_slice(tokens, first[:, None],
+                                                      (slot, 0))
+                pos = jax.lax.dynamic_update_slice(
+                    pos, jnp.full((1,), prompt.shape[1], jnp.int32), (slot,)
+                )
             return cache, tokens, pos, first[0]
 
         return jax.jit(admit_fused, donate_argnums=(1, 2, 3))
@@ -206,7 +213,8 @@ class ServingEngine:
 
     def __init__(self, model, params, *, num_slots: int, max_len: int,
                  sharder=None, seed: int = 0,
-                 program: ServeProgram | None = None):
+                 program: ServeProgram | None = None, replica: int = -1,
+                 spans: SpanLog | None = None):
         if program is None:
             program = ServeProgram(model, _spec(params), num_slots=num_slots,
                                    max_len=max_len, sharder=sharder)
@@ -228,21 +236,33 @@ class ServingEngine:
         self.slot_remaining = np.zeros(self.B, np.int64)
         self.outputs: dict[int, list[int]] = {}
         self.steps_dispatched = 0
+        #: lanes of the decode steps dispatched (steps x slots), and those
+        #: of a request past its budget earlier in the same fused block;
+        #: the rest emitted a token or were empty slots
+        self.lanes_stepped = 0
+        self.lanes_past_budget = 0
+        #: the worker node serving this replica, named in its span records
+        self.replica = int(replica)
+        #: the :class:`~repro.serve.spans.SpanLog` spans go to; None = off
+        self.spans = spans
 
     # -- slot admission ----------------------------------------------------------
 
     def admit(self, req: Request, slot: int) -> None:
         prompt = np.asarray(req.prompt, np.int32)[None, :]  # (1, S)
-        cache, tokens, pos, first = self.program.admit(
-            self.params, self.payload["cache"], self.payload["tokens"],
-            self.payload["pos"], prompt, np.int32(slot),
-        )
+        with span(self.spans, "ham.admit.dispatch", self.replica, req.rid):
+            cache, tokens, pos, first = self.program.admit(
+                self.params, self.payload["cache"], self.payload["tokens"],
+                self.payload["pos"], prompt, np.int32(slot),
+            )
         self.payload["cache"] = cache
         self.payload["tokens"] = tokens
         self.payload["pos"] = pos
         self.slot_req[slot] = req
         self.slot_remaining[slot] = req.max_new_tokens - 1
-        self.outputs[req.rid] = [int(first)]
+        with span(self.spans, "ham.admit.wait", self.replica, req.rid):
+            first = int(first)
+        self.outputs[req.rid] = [first]
 
     def free_slots(self) -> list[int]:
         return [i for i, r in enumerate(self.slot_req) if r is None]
@@ -289,6 +309,7 @@ class ServingEngine:
         self.steps_dispatched += 1
         if key == self.key_noop:
             return []
+        self.lanes_stepped += self.B
         toks = np.asarray(self.payload["tokens"][:, 0])
         emitted: list[tuple[int, int]] = []
         for slot in active:
@@ -331,22 +352,29 @@ class ServingEngine:
                 if all(r is None for r in self.slot_req):
                     break
             return out
-        self.payload["temp"] = jax.device_put(np.float32(0.0), self.device)
-        self.payload, toks = self.program.multi(k)(self.payload, self.params)
+        with span(self.spans, "ham.block.dispatch", self.replica):
+            self.payload["temp"] = jax.device_put(np.float32(0.0),
+                                                  self.device)
+            self.payload, toks = self.program.multi(k)(self.payload,
+                                                       self.params)
         self.steps_dispatched += k
-        toks_np = np.asarray(toks)  # (k, B)
+        with span(self.spans, "ham.block.wait", self.replica):
+            toks_np = np.asarray(toks)  # (k, B)
         emitted: list[tuple[int, int]] = []
-        for i in range(k):
-            for slot in active:
-                req = self.slot_req[slot]
-                if req is None:
-                    continue  # budget reached earlier in this block
-                tok = int(toks_np[i, slot])
-                emitted.append((req.rid, tok))
-                self.outputs[req.rid].append(tok)
-                self.slot_remaining[slot] -= 1
-                if self.slot_remaining[slot] <= 0:
-                    self.slot_req[slot] = None
+        with span(self.spans, "ham.block.emit", self.replica):
+            for i in range(k):
+                for slot in active:
+                    req = self.slot_req[slot]
+                    if req is None:
+                        continue  # budget reached earlier in this block
+                    tok = int(toks_np[i, slot])
+                    emitted.append((req.rid, tok))
+                    self.outputs[req.rid].append(tok)
+                    self.slot_remaining[slot] -= 1
+                    if self.slot_remaining[slot] <= 0:
+                        self.slot_req[slot] = None
+        self.lanes_stepped += k * self.B
+        self.lanes_past_budget += k * len(active) - len(emitted)
         return emitted
 
     def run(self, requests: list[Request]) -> dict[int, list[int]]:
@@ -497,6 +525,8 @@ class ClusterServingEngine:
         self.shed = 0                           # admission-overflow count
         self._pump: threading.Thread | None = None
         self._pump_stop = False
+        #: the span log of every replica and decode loop (enable_spans)
+        self._spans: SpanLog | None = None
         if self.worker_driven:
             _STREAM_SINKS[id(self.pool.host)] = self._on_stream
             _STREAM_BLOCK_SINKS[id(self.pool.host)] = self._on_stream_block
@@ -519,7 +549,7 @@ class ClusterServingEngine:
             self._model, self._replica_params(node),
             num_slots=self.slots_per_worker,
             max_len=self._program.max_len, seed=self._seed + node,
-            program=self._program,
+            program=self._program, replica=node, spans=self._spans,
         )
         _NODE_ENGINES[id(rt)] = eng
         if self.worker_driven:
@@ -528,7 +558,7 @@ class ClusterServingEngine:
             _NODE_LOOPS[id(rt)] = WorkerDecodeLoop(
                 rt, eng, host_node=self.pool.domain.host_node,
                 registry=self.registry, name=f"-{node}",
-                block=self.decode_block,
+                block=self.decode_block, replica=node, spans=self._spans,
             )
         self._engine_keys[node] = id(rt)
         with self._wd:
@@ -578,6 +608,18 @@ class ClusterServingEngine:
                 self._recover_node(node)
 
         return waiter
+
+    def enable_spans(self, capacity: int = 1 << 16) -> SpanLog:
+        """Turn the serving path's spans on, for every replica and decode
+        loop (those built later too), and return the log they share.
+        Spans are off until this is called (docs/serving.md, "Tracing")."""
+        if self._spans is None:
+            self._spans = SpanLog(capacity)
+        for key in self._engine_keys.values():
+            for holder in (_NODE_ENGINES.get(key), _NODE_LOOPS.get(key)):
+                if holder is not None:
+                    holder.spans = self._spans
+        return self._spans
 
     def serving_nodes(self) -> list[int]:
         """Live workers that currently hold an engine replica."""

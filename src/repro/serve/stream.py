@@ -20,6 +20,14 @@ empty and nothing is queued — an idle replica costs no CPU (the engine's
 ``step()`` early-out is the in-batch half of the same economy: a fully
 idle batch never dispatches the padded noop step).
 
+With a :class:`~repro.serve.spans.SpanLog` (``spans``, off by default)
+the loop records where each iteration's time goes: ``ham.loop.park``,
+``ham.loop.iter`` and, inside it, ``ham.loop.admit`` per request,
+``ham.loop.block`` and ``ham.loop.flush``; and per request, its wait in
+this loop's queue (``ham.req.queued``) and the time its first token is held
+on the worker until the flush (``ham.req.held``).  docs/serving.md,
+"Tracing", lists every span.
+
 Delivery/ordering contract (asserted by the stream tests):
 
 * per-request ordering — all stream calls for a request are emitted by one
@@ -56,6 +64,7 @@ from repro.core.flags import (
     STREAM_FAILED,
     STREAM_TOKEN,
 )
+from repro.serve.spans import span
 
 __all__ = ["WorkerDecodeLoop"]
 
@@ -74,7 +83,8 @@ class WorkerDecodeLoop:
     """
 
     def __init__(self, runtime, engine, *, host_node: int = 0,
-                 registry=None, name: str = "", block: int = 16):
+                 registry=None, name: str = "", block: int = 16,
+                 replica: int = -1, spans=None):
         self._rt = runtime
         self._eng = engine
         self._host = int(host_node)
@@ -86,7 +96,8 @@ class WorkerDecodeLoop:
         #: by block * step_time (microscopic next to the TTFT SLO).
         self._block = max(1, int(block))
         self._cv = threading.Condition()
-        #: queued admissions: (prompt, rid, gen, max_new, temp, deadline_s)
+        #: queued admissions: (prompt, rid, gen, max_new, temp, deadline_s,
+        #: enqueue time in perf_counter_ns, or None with spans off)
         self._admits: deque = deque()
         #: cancel requests: (rid, gen, status)
         self._cancels: list[tuple[int, int, int]] = []
@@ -96,8 +107,15 @@ class WorkerDecodeLoop:
         self._stop = False
         #: the exception that stopped this loop (None while it runs)
         self.error: Exception | None = None
-        self.stats = {"steps": 0, "tokens": 0, "frames": 0, "parks": 0,
+        self.stats = {"steps": 0, "tokens": 0, "frames": 0,
                       "expired": 0, "cancelled": 0}
+        #: the worker node this loop serves, named in its span records
+        self.replica = int(replica)
+        #: the :class:`~repro.serve.spans.SpanLog` spans go to; None = off
+        self.spans = spans
+        #: (rid, end of its admission) of this iteration's admissions,
+        #: whose first tokens wait for the iteration's flush
+        self._held: list[tuple[int, int]] = []
         self._thread = threading.Thread(
             target=self._run, name=f"ham-decode-loop{name}", daemon=True
         )
@@ -116,8 +134,9 @@ class WorkerDecodeLoop:
                 raise OffloadError(
                     f"decode loop is stopped on this worker{why}"
                 ) from self.error
+            t_enq = None if self.spans is None else time.perf_counter_ns()
             self._admits.append((prompt, rid, gen, max_new_tokens,
-                                 temperature, deadline_s))
+                                 temperature, deadline_s, t_enq))
             self._cv.notify()
 
     def cancel(self, rid: int, gen: int, status: int) -> None:
@@ -174,9 +193,10 @@ class WorkerDecodeLoop:
         eng = self._eng
         while True:
             with self._cv:
-                while not self._stop and self._idle():
-                    self.stats["parks"] += 1
-                    self._cv.wait()
+                if not self._stop and self._idle():
+                    with span(self.spans, "ham.loop.park", self.replica):
+                        while not self._stop and self._idle():
+                            self._cv.wait()
                 if self._stop:
                     return
                 cancels, self._cancels = self._cancels, []
@@ -184,34 +204,35 @@ class WorkerDecodeLoop:
                 free = len(eng.free_slots())
                 while self._admits and len(admits) < free:
                     admits.append(self._admits.popleft())
-            calls: list = []
-            now = time.monotonic()
-            # 1. cancels and expiries leave the batch BEFORE this step
-            for rid, gen, status in cancels:
-                live = self._live.get(rid)
-                if live is not None and live["gen"] == gen:
-                    self.stats["cancelled"] += 1
-                    self._finish(f2f, rid, status, calls)
-                else:
-                    # never seen (admit still in flight or already gone):
-                    # tombstone the generation and ack unconditionally so
-                    # the host-side cancel cannot hang
-                    self._tombstones.append((rid, gen))
-                    calls.append(self._stream_call(f2f, rid, gen, 0, -1,
-                                                   status))
-            for rid in [r for r, lv in self._live.items()
-                        if lv["expires"] is not None
-                        and now >= lv["expires"]]:
-                self.stats["expired"] += 1
-                self._finish(f2f, rid, STREAM_EXPIRED, calls)
-            try:
-                self._admit(f2f, admits, now, calls)
-                self._decode_block(f2f, calls)
-            except Exception as exc:  # noqa: BLE001 — fail requests, not hang
-                self._fail(f2f, exc, admits, calls)
-                return
-            if calls:
-                self._flush(calls)
+            with span(self.spans, "ham.loop.iter", self.replica):
+                calls: list = []
+                now = time.monotonic()
+                # 1. cancels and expiries leave the batch BEFORE this step
+                for rid, gen, status in cancels:
+                    live = self._live.get(rid)
+                    if live is not None and live["gen"] == gen:
+                        self.stats["cancelled"] += 1
+                        self._finish(f2f, rid, status, calls)
+                    else:
+                        # never seen (admit still in flight or already gone):
+                        # tombstone the generation and ack unconditionally so
+                        # the host-side cancel cannot hang
+                        self._tombstones.append((rid, gen))
+                        calls.append(self._stream_call(f2f, rid, gen, 0, -1,
+                                                       status))
+                for rid in [r for r, lv in self._live.items()
+                            if lv["expires"] is not None
+                            and now >= lv["expires"]]:
+                    self.stats["expired"] += 1
+                    self._finish(f2f, rid, STREAM_EXPIRED, calls)
+                try:
+                    self._admit(f2f, admits, now, calls)
+                    self._decode_block(f2f, calls)
+                except Exception as exc:  # noqa: BLE001 — fail requests, not hang
+                    self._fail(f2f, exc, admits, calls)
+                    return
+                if calls:
+                    self._flush(calls)
 
     def _admit(self, f2f, admits: list, now: float, calls: list) -> None:
         """Admissions into freed slots (prefill runs HERE, on the worker,
@@ -221,7 +242,7 @@ class WorkerDecodeLoop:
 
         eng = self._eng
         while admits:
-            prompt, rid, gen, max_new, temp, deadline_s = admits[0]
+            prompt, rid, gen, max_new, temp, deadline_s, t_enq = admits[0]
             if (rid, gen) in self._tombstones:
                 admits.pop(0)
                 calls.append(self._stream_call(f2f, rid, gen, 0, -1,
@@ -233,31 +254,41 @@ class WorkerDecodeLoop:
                     self._admits.extendleft(reversed(admits))
                 admits.clear()
                 return
-            eng.admit(Request(prompt=prompt, max_new_tokens=max_new,
-                              temperature=temp, rid=rid), free_now[0])
-            admits.pop(0)
-            first = int(eng.outputs[rid][0])
-            if max_new <= 1:
-                # single-token lease: the prefill's argmax IS the whole
-                # request — free the slot without a decode step
-                eng.evict(rid)
-                self._tombstones.append((rid, gen))
-                status = STREAM_DONE
-            else:
-                self._live[rid] = {
-                    "gen": gen, "seq": 1, "remaining": max_new - 1,
-                    "expires": now + deadline_s if deadline_s > 0 else None,
-                }
-                status = STREAM_TOKEN
-            self.stats["tokens"] += 1
-            calls.append(self._stream_call(f2f, rid, gen, 0, first, status))
+            with span(self.spans, "ham.loop.admit", self.replica,
+                      rid) as sp:
+                eng.admit(Request(prompt=prompt, max_new_tokens=max_new,
+                                  temperature=temp, rid=rid), free_now[0])
+                admits.pop(0)
+                first = int(eng.outputs[rid][0])
+                if max_new <= 1:
+                    # single-token lease: the prefill's argmax IS the whole
+                    # request — free the slot without a decode step
+                    eng.evict(rid)
+                    self._tombstones.append((rid, gen))
+                    status = STREAM_DONE
+                else:
+                    self._live[rid] = {
+                        "gen": gen, "seq": 1, "remaining": max_new - 1,
+                        "expires": (now + deadline_s if deadline_s > 0
+                                    else None),
+                    }
+                    status = STREAM_TOKEN
+                self.stats["tokens"] += 1
+                calls.append(self._stream_call(f2f, rid, gen, 0, first,
+                                               status))
+            if sp is not None:
+                if t_enq is not None:
+                    sp.log.record("ham.req.queued", self.replica, rid,
+                                  t_enq, sp.t0)
+                self._held.append((rid, sp.t1))
 
     def _decode_block(self, f2f, calls: list) -> None:
         """One fused block of batched decode steps (none when the batch is
         empty): per-dispatch overhead amortised over the whole block."""
         from repro.serve.handlers import STREAM_BLOCK_MAX
 
-        emitted = self._eng.step_many(self._block)
+        with span(self.spans, "ham.loop.block", self.replica):
+            emitted = self._eng.step_many(self._block)
         if emitted:
             self.stats["steps"] += 1
         # group each request's tokens (emitted is step-major, so the
@@ -305,6 +336,15 @@ class WorkerDecodeLoop:
     def _flush(self, calls: list) -> None:
         """Ship this iteration's stream calls as fused oneways: msg_id 0
         segments in FLAG_FUSED frames (one frame per FUSE_MAX_SEGMENTS)."""
+        with span(self.spans, "ham.loop.flush", self.replica) as sp:
+            if sp is not None:
+                for rid, t_admitted in self._held:
+                    sp.log.record("ham.req.held", self.replica, rid,
+                                  t_admitted, sp.t0)
+            self._held.clear()
+            self._send(calls)
+
+    def _send(self, calls: list) -> None:
         from repro.offload.runtime import FUSE_MAX_SEGMENTS
 
         if self._rt._stop.is_set():

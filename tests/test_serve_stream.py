@@ -16,6 +16,7 @@ from repro.configs import get_reduced
 from repro.core.flags import STREAM_CANCELLED, STREAM_DONE, STREAM_EXPIRED
 from repro.models.api import build_model
 from repro.serve.engine import ClusterServingEngine, Request, ServingEngine
+from repro.serve.handlers import _NODE_ENGINES, _NODE_LOOPS
 
 
 @pytest.fixture(scope="module")
@@ -275,3 +276,154 @@ def test_decode_loop_failure_reaches_wait(model_and_params, monkeypatch):
             assert eng._done[rids[0]] == STREAM_FAILED
     finally:
         eng.close()
+
+
+# -- tracing: program spans and lane counters ------------------------------
+
+
+def _serve_some(model, params, *, spans: bool, n=6, max_new=8):
+    """Six requests through a worker-driven cluster of two replicas;
+    returns (span log or None, rid -> host events)."""
+    cfg = model.cfg
+    eng = ClusterServingEngine(model, params, num_workers=2,
+                               slots_per_worker=2, max_len=32,
+                               decode_block=4)
+    try:
+        log = eng.enable_spans() if spans else None
+        rids = [eng.submit_request(r, shed=False)
+                for r in _reqs(cfg, n, max_new=max_new)]
+        eng.wait(rids, timeout=120.0)
+        with eng._wd:
+            events = {r: dict(eng._events[r]) for r in rids}
+            assert all(len(eng._transcripts[r]) == max_new for r in rids)
+        holders = [h for n in eng._engine_keys.values()
+                   for h in (_NODE_ENGINES[n], _NODE_LOOPS[n])]
+        assert all(h.spans is log for h in holders)
+    finally:
+        eng.close()
+    return log, events
+
+
+def test_spans_off_record_nothing_and_open_no_annotation(model_and_params,
+                                                          monkeypatch):
+    """Spans are off by default: a served run holds no log, and the
+    profiler's annotation is never entered."""
+    import jax
+
+    def refuse(*a, **k):
+        raise AssertionError("TraceAnnotation entered with spans off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    model, params = model_and_params
+    log, events = _serve_some(model, params, spans=False)
+    assert log is None and len(events) == 6
+
+
+def test_spans_order_each_request_and_nest(model_and_params):
+    """With spans on, each request has one ``ham.req.queued`` and one
+    ``ham.req.held``, and enqueue <= admission start <= admission end <=
+    flush <= the host's receipt of the first token; every child span lies
+    inside its parent on the same replica."""
+    import time
+
+    model, params = model_and_params
+    log, events = _serve_some(model, params, spans=True)
+    recs = log.records()
+    assert log.dropped == 0 and recs
+    assert all(name.startswith("ham.") for name, *_ in recs)
+    to_perf = time.perf_counter_ns() - time.monotonic_ns()
+    for rid, ev in events.items():
+        mine = {}
+        for name, rep, r, a, b in recs:
+            if r == rid and name in ("ham.req.queued", "ham.loop.admit",
+                                     "ham.req.held"):
+                mine.setdefault(name, []).append((rep, a, b))
+        assert {k: len(v) for k, v in mine.items()} == {
+            "ham.req.queued": 1, "ham.loop.admit": 1, "ham.req.held": 1}
+        (_, enq, q_end), = mine["ham.req.queued"]
+        (_, a0, a1), = mine["ham.loop.admit"]
+        (_, h0, flush), = mine["ham.req.held"]
+        first = ev["t_first"] * 1e9 + to_perf
+        assert enq <= q_end == a0 <= a1 == h0 <= flush <= first + 1e3
+    parents = {"ham.admit.dispatch": "ham.loop.admit",
+               "ham.admit.wait": "ham.loop.admit",
+               "ham.block.dispatch": "ham.loop.block",
+               "ham.block.wait": "ham.loop.block",
+               "ham.block.emit": "ham.loop.block",
+               "ham.loop.admit": "ham.loop.iter",
+               "ham.loop.block": "ham.loop.iter",
+               "ham.loop.flush": "ham.loop.iter"}
+    seen = set()
+    for name, rep, rid, a, b in recs:
+        assert a <= b
+        if name not in parents:
+            continue
+        seen.add(name)
+        assert any(n == parents[name] and rp == rep and pa <= a and b <= pb
+                   and (r < 0 or r == rid)
+                   for n, rp, r, pa, pb in recs), (name, rep, rid)
+    assert seen == set(parents)
+    assert any(name == "ham.loop.park" for name, *_ in recs)
+
+
+def test_span_ring_counts_what_it_drops():
+    from repro.serve.spans import SpanLog
+
+    log = SpanLog(capacity=4)
+    for i in range(10):
+        log.record("ham.x", 1, i, i, i + 1)
+    assert log.dropped == 6
+    assert [r[2] for r in log.records()] == [6, 7, 8, 9]
+    with pytest.raises(ValueError):
+        SpanLog(capacity=0)
+
+
+def test_lane_counters_split_the_block_by_hand(model_and_params):
+    """2 slots, budgets 3 and 20, blocks of 16.  Admission emits each
+    request's first token.  Block 1: slot 0 emits 2 and is past its budget
+    for 14 lanes, slot 1 emits 16.  Block 2: slot 0 is empty (16 lanes),
+    slot 1 emits its last 3 and is past its budget for 13."""
+    model, params = model_and_params
+    cfg = model.cfg
+    eng = ServingEngine(model, params, num_slots=2, max_len=64)
+    eng.admit(Request(prompt=np.arange(4) % cfg.vocab_size,
+                      max_new_tokens=3, rid=0), 0)
+    eng.admit(Request(prompt=np.arange(5) % cfg.vocab_size,
+                      max_new_tokens=20, rid=1), 1)
+    emitted = len(eng.step_many(16))
+    assert (eng.lanes_stepped, emitted, eng.lanes_past_budget) == (32, 18, 14)
+    emitted += len(eng.step_many(16))
+    assert (eng.lanes_stepped, emitted, eng.lanes_past_budget) == (64, 21, 27)
+    empty = eng.lanes_stepped - emitted - eng.lanes_past_budget
+    assert empty == 16
+    assert {r: len(v) for r, v in eng.outputs.items()} == {0: 3, 1: 20}
+    assert eng.step_many(16) == [] and eng.lanes_stepped == 64
+
+
+def test_executable_names_match_the_trace_reduction(model_and_params):
+    """The benchmark's trace reduction finds fused admission and the fused
+    block by their module names (bench/trace.py ``EXECUTABLES``); a rename
+    fails here.  The named scopes reach the lowered program's metadata."""
+    import re
+
+    from bench import trace as tr
+    from repro.serve.engine import ServeProgram, _spec
+
+    model, params = model_and_params
+    prog = ServeProgram(model, _spec(params), num_slots=2, max_len=32)
+    pl = prog.init_payload(0)
+    lowered = {
+        "admit": prog.admit.lower(params, pl["cache"], pl["tokens"],
+                                  pl["pos"], np.zeros((1, 8), np.int32),
+                                  np.int32(0)),
+        "block": prog.multi(16).lower(pl, params),
+    }
+    scopes = {"admit": ("prefill", "cache_insert", "first_token"),
+              "block": ("decode_step",)}
+    assert set(lowered) == set(tr.EXECUTABLES)
+    for kind, low in lowered.items():
+        module = re.search(r"module @(\S+)", low.as_text()).group(1)
+        assert tr._executable(f"{module}(7)") == kind, module
+        text = low.as_text(debug_info=True)
+        for scope in scopes[kind]:
+            assert f'"{scope}' in text or f"/{scope}" in text, scope
