@@ -231,6 +231,7 @@ def attention_apply(
     window: int | None = None,
     cache: dict | None = None,
     cache_pos=None,
+    layer=None,
     x_kv=None,
     sharder=None,
     static_cache: bool = False,
@@ -244,6 +245,12 @@ def attention_apply(
                        from k, v when ``return_cache`` via prefill wrapper)
     * decode:          cache={'k','v'} (b, S, hk, hd); the t new tokens are
                        written at ``cache_pos`` and attend over the cache.
+    * stacked decode:  cache={'k','v'} (L, b, S, hk, hd) with ``layer`` the
+                       traced layer index: only the new rows are written,
+                       in place at ``(layer, b, cache_pos)``, attention reads
+                       ``cache[layer]``, and the whole stacked cache is
+                       returned.  A scan over layers that carries the cache
+                       then moves no per-layer slab out and back.
     """
     q, k, v = _project_qkv(p, x, dtype, x_kv=x_kv)
     if rope_theta is not None:
@@ -304,19 +311,25 @@ def attention_apply(
         # per-slot decode (continuous batching in the serving engine);
         # cache slots are linear, or a ring buffer of size S=window for
         # windowed attention (long-context hybrid cells)
-        S = cache["k"].shape[1]
+        S = cache["k"].shape[-3]
         per_slot = hasattr(cache_pos, "ndim") and cache_pos.ndim == 1
+        widx = (cache_pos % S) if window is not None else cache_pos
+        lead = () if layer is None else (layer,)
         if per_slot:
-            B = cache["k"].shape[0]
-            widx = (cache_pos % S) if window is not None else cache_pos
-            bidx = jnp.arange(B)
-            ck = cache["k"].at[bidx, widx].set(k[:, 0])
-            cv = cache["v"].at[bidx, widx].set(v[:, 0])
+            bidx = jnp.arange(cache["k"].shape[-4])
+
+            def write(c, new):
+                return c.at[lead + (bidx, widx)].set(new[:, 0])
         else:
-            write_idx = (cache_pos % S) if window is not None else cache_pos
-            ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, write_idx, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, write_idx, 0, 0))
-        new_cache = {"k": ck, "v": cv}
+            def write(c, new):
+                new = new if layer is None else new[None]
+                return jax.lax.dynamic_update_slice(
+                    c, new, lead + (0, widx, 0, 0))
+        new_cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
+        ck, cv = new_cache["k"], new_cache["v"]
+        if layer is not None:
+            ck = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
         kj = jnp.arange(S)[None, :]
         # qi: (t, 1) or (B, t, 1) absolute query positions
         qi = positions[..., :, None]

@@ -44,16 +44,19 @@ def layer_init(key, cfg: ModelConfig):
 
 
 def layer_apply(p, x, cfg: ModelConfig, *, positions, sharder=None,
-                cache=None, cache_pos=None, causal=True, window=None):
+                cache=None, cache_pos=None, layer=None, causal=True,
+                window=None):
     """Pre-norm block: x + attn(ln(x)); x + mlp(ln(x)).  Returns
-    (x, new_cache, aux)."""
+    (x, new_cache, aux).  With ``layer``, ``cache`` is every layer's cache
+    stacked and is updated in place (``attention_apply``)."""
     dt = jnp.dtype(cfg.dtype)
     h = L.rmsnorm(p["ln_attn"], x, cfg.norm_eps)
     attn_out, new_cache = L.attention_apply(
         p["attn"], h, spec=_attn_spec(cfg), dtype=dt,
         rope_theta=cfg.rope_theta, positions=positions, causal=causal,
-        window=window, cache=cache, cache_pos=cache_pos, sharder=sharder,
-        attn_chunk=cfg.attn_chunk, causal_skip=cfg.attn_causal_skip,
+        window=window, cache=cache, cache_pos=cache_pos, layer=layer,
+        sharder=sharder, attn_chunk=cfg.attn_chunk,
+        causal_skip=cfg.attn_causal_skip,
     )
     x = x + attn_out
     h = L.rmsnorm(p["ln_mlp"], x, cfg.norm_eps)
@@ -228,12 +231,20 @@ def lm_decode_step(p, cache, batch, cfg: ModelConfig, *, sharder=None,
         return (x, aux + a), new_cache_l
 
     if cfg.scan_layers:
-        # the cache rides in the carry and each layer writes its slice back
-        # in place: emitted as scan outputs instead, the new cache would be
-        # a second whole copy of the cache beside the donated one
+        # the cache rides in the carry: emitted as scan outputs instead, the
+        # new cache would be a second whole copy of it beside the donated
+        # one.  Each layer writes its new K/V rows into the stacked carry in
+        # place and attends over its own layer of it; only the int8 cache
+        # still takes its layer's slab out and writes it back whole
         def carried(carry, layer_in):
             x, aux, cache = carry
             layer_p, i = layer_in
+            if "k_scale" not in cache:
+                x, cache, a = layer_apply(
+                    layer_p, x, cfg, positions=positions, sharder=sharder,
+                    cache=cache, cache_pos=pos, layer=i, window=window,
+                )
+                return (x, aux + a, cache), None
             cache_l = jax.tree_util.tree_map(
                 lambda c: jax.lax.dynamic_index_in_dim(c, i, keepdims=False),
                 cache)

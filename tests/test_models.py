@@ -94,6 +94,41 @@ def test_decode_matches_full_forward(arch):
     assert max(errs) < 5e-3, f"decode diverges from forward: {max(errs)}"
 
 
+@pytest.mark.parametrize("arch", ["qwen1.5-4b", "internlm2-20b"])  # MHA + bias, GQA
+def test_per_slot_decode_writes_only_the_new_rows(arch):
+    """The scan path writes each slot's K/V row into the stacked cache in
+    place; every other cache element is left bit for bit, and the logits
+    are those of the per-layer path (scan off)."""
+    import dataclasses
+
+    from repro.models.transformer import lm_decode_step
+
+    cfg = get_reduced(arch)
+    assert cfg.scan_layers
+    B, S = 4, 16
+    params = build_model(cfg).init(jax.random.PRNGKey(4))
+    rng = np.random.default_rng(4)
+    shape = (cfg.num_layers, B, S, cfg.num_kv_heads, cfg.resolved_head_dim)
+    cache = {n: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+             for n in ("k", "v")}
+    pos = np.array([0, 5, 11, S - 1], np.int32)
+    step = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 1)),
+                                  jnp.int32),
+            "pos": jnp.asarray(pos)}
+    logits, new = jax.jit(lambda c: lm_decode_step(params, c, step, cfg))(cache)
+    ref_logits, ref = lm_decode_step(
+        params, cache, step, dataclasses.replace(cfg, scan_layers=False))
+    written = np.zeros(shape[:3], bool)
+    written[:, np.arange(B), pos] = True
+    for n in ("k", "v"):
+        old, got = np.asarray(cache[n]), np.asarray(new[n])
+        np.testing.assert_array_equal(got[~written], old[~written])
+        np.testing.assert_allclose(got[written], np.asarray(ref[n])[written],
+                                   atol=5e-3)
+    err = float(jnp.abs(logits - ref_logits).max())
+    assert err < 5e-3, f"in-place decode diverges from the per-layer path: {err}"
+
+
 def test_full_config_param_counts_match_published():
     expect = {
         "llama3-405b": 405.8e9, "nemotron-4-340b": 341.0e9,

@@ -12,6 +12,7 @@ this file.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +98,77 @@ def test_fused_admission_compiles_and_fits(serve_shapes, one_chip):
     slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     _fits(program.admit.lower(params, payload["cache"], payload["tokens"],
                               payload["pos"], prompt, slot).compile())
+
+
+#: ``temp_size_in_bytes`` of ``multi(16)`` at these shapes while the decode
+#: step still copied each layer's K/V slab out of the stacked cache and
+#: back; writing the rows in place must need no more
+SLAB_COPY_BLOCK_TEMP_BYTES = {"mha": 1007270400, "gqa": 202319360}
+
+
+def _top_level(hlo: str):
+    """``(name, result dims)`` of every array-valued instruction of a
+    compiled module that lies outside the bodies of fused computations."""
+    bodies: dict[str, list[str]] = {}
+    fused: set[str] = set()
+    name = None
+    for line in hlo.splitlines():
+        if not line.startswith(" "):  # a computation opens or closes
+            m = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+            name = m.group(1) if m and line.endswith("{") else None
+            if name:
+                bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+            if " fusion(" in line:
+                fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]", line)
+            if m:
+                yield m.group(1), tuple(int(d) for d in m.group(2).split(",")
+                                        if d)
+
+
+@pytest.mark.parametrize("attn", ["mha", "gqa"])
+def test_decode_block_moves_no_kv_slab(attn, serve_shapes, one_chip):
+    """The fused decode block writes each token's K/V rows into the stacked
+    cache in place: no dynamic-slice or dynamic-update-slice instruction
+    or fusion at the top level of the block's computations yields a
+    layer's K/V slab ``(B, S, hk, hd)`` or the stacked cache ``(L, B, S,
+    hk, hd)``, as the copy out of the stack and the write back did, and the
+    block needs no more temporaries than it did with those copies.
+
+    MHA is the chip smoke's qwen1.5-4b; GQA is internlm2-20b at 2 of its 48
+    layers (same widths, a shorter compile).  A GQA layer's score dot reads
+    its slab through a ``(1, B, S, hk, hd)`` dynamic slice staged in on-chip
+    memory: the one read of the layer's cache that attention needs, which
+    the MHA scores fuse instead."""
+    if attn == "mha":
+        program, params, payload = serve_shapes
+        cfg = program.model.cfg
+    else:
+        from repro.configs import get_config
+        from repro.models.api import build_model
+        from repro.serve.engine import ServeProgram
+
+        cfg = dataclasses.replace(get_config("internlm2-20b"), num_layers=2,
+                                  param_dtype="bfloat16")
+        model = build_model(cfg)
+        params = _on(one_chip,
+                     jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        program = ServeProgram(model, params, num_slots=4, max_len=512)
+        payload = _on(one_chip, program.payload_spec)
+    slab = (program.B, program.max_len, cfg.num_kv_heads,
+            cfg.resolved_head_dim)
+    compiled = program.multi(16).lower(payload, params).compile()
+    copies = [(n, d) for n, d in _top_level(compiled.as_text())
+              if "dynamic" in n and d in (slab, (cfg.num_layers,) + slab)]
+    assert not copies, f"the decode block copies KV slabs: {copies}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= SLAB_COPY_BLOCK_TEMP_BYTES[attn], temp
 
 
 def _kernel_cases():
