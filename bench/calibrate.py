@@ -17,7 +17,6 @@ is set between them, as ``PERF.md`` records.
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import sys
 from pathlib import Path
@@ -44,7 +43,7 @@ def main(argv=None) -> int:
     if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
         print("calibrate: needs the cell's TPU chips", file=sys.stderr)
         return 2
-    ref = importlib.import_module(f"bench.reference.{c['reference']}")
+    ref = run.reference(c)
     plain = ref.gaps
     seen: list = []
 

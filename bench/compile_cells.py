@@ -33,7 +33,7 @@ def main(argv: list[str]) -> int:
     from repro.serve.engine import ServeProgram
 
     from bench import traffic
-    from bench.run import program_config
+    from bench.run import family
 
     jax.config.update("jax_enable_compilation_cache", False)
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -52,7 +52,7 @@ def main(argv: list[str]) -> int:
         c = json.loads((ROOT / "bench" / "configs"
                         / f"{cell['config']}.json").read_text())
         sv = c["serving"]
-        model = build_model(program_config(c))
+        model = build_model(family(c).program_config(c))
         params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
         prog = ServeProgram(model, params, num_slots=sv["slots_per_replica"],
                             max_len=sv["max_len"])

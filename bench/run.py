@@ -8,7 +8,8 @@ configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``), and takes as many chips as it says, one
 serving replica per chip.  In order, in this one process:
 
-1. the weights are drawn from ``--seed`` on the device in bf16, and
+1. the configuration's family (``bench/families/<family>.py``) draws the
+   weights from ``--seed`` on the device in bf16, and
    ``ClusterServingEngine`` is built in worker-driven mode;
 2. every shape the mix uses is warmed on every replica: fused admission at
    each prompt length of the mix, and the fused decode block;
@@ -16,8 +17,9 @@ serving replica per chip.  In order, in this one process:
 4. the window: ``--seconds`` of traffic through ``submit_request``, every
    request timed on the host clock (an open loop from when it was due);
 5. the served tokens of a sample of the window's requests are checked
-   against the float32 reference (``bench/reference``), once the program's
-   state is freed, and the last line is printed.
+   against the configuration's float32 reference
+   (``bench/reference/<reference>.py``), once the program's state is
+   freed, and the last line is printed.
 
 With ``--trace 1`` the profiler records a few seconds in the middle of the
 window, and the cell's per-layer metrics are printed instead of its
@@ -51,7 +53,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import counts, traffic  # noqa: E402
+from bench import traffic  # noqa: E402
 from bench.stats import percentile  # noqa: E402
 from bench import trace as tr  # noqa: E402
 
@@ -85,16 +87,50 @@ def load_config(name: str, base: Path = BENCH) -> dict:
     return load_json(base / "configs" / f"{name}.json")
 
 
+_MODULES: dict[Path, types.ModuleType] = {}
+
+
+def module_at(path: Path) -> types.ModuleType:
+    """The module in the file ``path``, run once per process.  A file of
+    one of this package's own subpackages (``bench/families``,
+    ``bench/reference``) is the module ``import bench.<dir>.<name>``
+    gives, so that a tool that imports it sees the same object; any other
+    file, such as one in a copy of ``bench/``, is a module of its own."""
+    path = path.resolve()
+    if path in _MODULES:
+        return _MODULES[path]
+    if not path.is_file():
+        raise FileNotFoundError(f"no file {path}")
+    if (path.parent.parent == BENCH.resolve()
+            and (path.parent / "__init__.py").is_file()):
+        mod = importlib.import_module(f"bench.{path.parent.name}.{path.stem}")
+    else:
+        name = f"_bench_{path.parent.name}_{len(_MODULES)}"
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod  # a dataclass in it looks itself up there
+        spec.loader.exec_module(mod)
+    _MODULES[path] = mod
+    return mod
+
+
 def reader(name: str, base: Path = BENCH):
     """``read(ctx)`` of ``bench/metrics/<name>.py``."""
-    path = base / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    if spec is None or not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {name!r} ({path})")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module_at(base / "metrics" / f"{name}.py").read
+
+
+def family(c: dict, base: Path = BENCH) -> types.ModuleType:
+    """``bench/families/<family>.py`` of the configuration ``c``: its
+    ``program_config``, ``shapes``, ``make_weights`` and ``to_program``,
+    and where it has them ``counters`` and ``parts`` (``bench/README.md``,
+    "Adding to the benchmark")."""
+    return module_at(base / "families" / f"{c['family']}.py")
+
+
+def reference(c: dict, base: Path = BENCH) -> types.ModuleType:
+    """``bench/reference/<reference>.py`` of the configuration ``c``, with
+    its ``gaps``."""
+    return module_at(base / "reference" / f"{c['reference']}.py")
 
 
 def peak_of(kind: str) -> dict:
@@ -102,26 +138,6 @@ def peak_of(kind: str) -> dict:
     if kind not in table:
         raise KeyError(f"device kind {kind!r} is not in bench/peaks.json")
     return table[kind]
-
-
-def program_config(c: dict):
-    """The program's ``ModelConfig`` for a dense decoder configuration,
-    with every setting the file states."""
-    from repro.models.config import ModelConfig
-
-    if c["family"] != "dense":
-        raise ValueError(f"no builder for family {c['family']!r}")
-    return ModelConfig(
-        name=Path(c.get("name", "bench")).name, family="dense",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], qkv_bias=bool(c["qkv_bias"]),
-        mlp="swiglu", rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        dtype="bfloat16", param_dtype=c["serving"]["param_dtype"],
-    )
 
 
 # -- the program under test -------------------------------------------------
@@ -135,19 +151,28 @@ def replicas(eng) -> list[tuple[int, object, object]]:
             for n, k in sorted(eng._engine_keys.items())]
 
 
-def counters(eng) -> dict:
+def counters(eng, program=None) -> dict:
+    """The scheduler's and the decode loops' counters, and under
+    ``"program"`` the sum over replicas of each counter that ``program``
+    (a family's ``counters``, or None) reads from a ``ServingEngine``."""
     reps = replicas(eng)
     st = eng.sched.stats
+    summed: dict = {}
+    if program is not None:
+        for _, e, _ in reps:
+            for k, v in program(e).items():
+                summed[k] = summed.get(k, 0) + v
     return {
         "submitted": st["submitted"], "oneways": st["oneways"],
         "routed": dict(st["routed"]),
         "frames": sum(lp.stats["frames"] for _, _, lp in reps),
         "loop_tokens": sum(lp.stats["tokens"] for _, _, lp in reps),
         "steps": sum(e.steps_dispatched for _, e, _ in reps),
+        "program": summed,
     }
 
 
-def build(c: dict, seed: int, devices):
+def build(c: dict, seed: int, devices, base: Path = BENCH):
     import jax
 
     from repro.models.api import build_model
@@ -155,11 +180,11 @@ def build(c: dict, seed: int, devices):
 
     from bench import weights
 
-    shapes = counts.Shapes.from_config(c)
-    model = build_model(program_config(c))
+    fam = family(c, base)
+    model = build_model(fam.program_config(c))
     key = jax.device_put(weights.root_key(seed), devices[0])
-    params = weights.to_program(
-        jax.block_until_ready(weights.make(key, shapes)))
+    params = fam.to_program(
+        jax.block_until_ready(fam.make_weights(key, fam.shapes(c))))
     weights.check_tree(params, jax.eval_shape(model.init,
                                               jax.random.PRNGKey(0)))
     sv = c["serving"]
@@ -190,9 +215,11 @@ def warm(eng, lengths: list[int], block: int) -> None:
         jax.block_until_ready(rep.payload)
 
 
-def install_spans(eng, shapes: counts.Shapes, spans: tr.Spans) -> None:
+def install_spans(eng, shapes, spans: tr.Spans, parts=None) -> None:
     """Wrap each replica's ``admit`` and ``step_many`` in a named host span
-    that the profiler records, noting the work each call needed."""
+    that the profiler records, noting the work each call needed
+    (``shapes``, a family's work counts) and, with ``parts`` (a family's),
+    the work of each named scope in it."""
     import jax
 
     for node, rep, _ in replicas(eng):
@@ -204,6 +231,8 @@ def install_spans(eng, shapes: counts.Shapes, spans: tr.Spans) -> None:
             with jax.profiler.TraceAnnotation(name):
                 out = _admit(req, slot)
             spans.work[name] = shapes.prefill(len(req.prompt))
+            if parts is not None:
+                spans.parts[name] = parts(shapes, "admit", len(req.prompt))
             return out
 
         def block_spanned(k, _step=step_many, _rep=rep, _node=node):
@@ -218,8 +247,10 @@ def install_spans(eng, shapes: counts.Shapes, spans: tr.Spans) -> None:
                 n[rid] = n.get(rid, 0) + 1
             lanes = [before[rid] + j for rid, m in n.items()
                      for j in range(m)]
-            spans.work[name] = shapes.decode_steps(max(n.values(), default=0),
-                                                   lanes)
+            steps = max(n.values(), default=0)
+            spans.work[name] = shapes.decode_steps(steps, lanes)
+            if parts is not None:
+                spans.parts[name] = parts(shapes, "block", steps, lanes)
             return emitted
 
         rep.admit, rep.step_many = admit_spanned, block_spanned
@@ -229,9 +260,10 @@ def install_spans(eng, shapes: counts.Shapes, spans: tr.Spans) -> None:
 
 
 def drive(eng, gen: traffic.Traffic, mix: dict, seconds: float,
-          on_tick=None) -> dict:
+          on_tick=None, program=None) -> dict:
     """Run the mix: ``warmup_s`` of steady state, then the window.  Returns
-    the window, the requests (with their due times) and counter snapshots.
+    the window, the requests (with their due times) and counter snapshots
+    (:func:`counters`, with the family's ``program`` counters).
     After the window closes the traffic goes on, unmeasured, until every
     request of the window has finished or ``GRACE_S`` has passed."""
     from repro.serve.engine import Request
@@ -263,9 +295,9 @@ def drive(eng, gen: traffic.Traffic, mix: dict, seconds: float,
     while True:
         now = time.monotonic()
         if "t0" not in snap and now >= t0:
-            snap["t0"] = counters(eng)
+            snap["t0"] = counters(eng, program)
         if "t1" not in snap and now >= t1:
-            snap["t1"] = counters(eng)
+            snap["t1"] = counters(eng, program)
         if on_tick is not None:
             on_tick(now, t0, t1)
         if now >= t1:
@@ -309,11 +341,13 @@ def finished(q: dict) -> bool:
             and len(q["served"]) == q["budget"])
 
 
-def check(c: dict, seed: int, run: dict) -> tuple[bool, dict, int]:
+def check(c: dict, seed: int, run: dict,
+          base: Path = BENCH) -> tuple[bool, dict, int]:
     """Compare a sample of the window's finished requests with the float32
-    reference: the widest gap by which a served token's logit lies below
-    the reference's best at its position.  Returns ``correct``, each
-    number compared beside its limit, and how many tokens were compared."""
+    reference (:func:`reference`): the widest gap by which a served
+    token's logit lies below the reference's best at its position.
+    Returns ``correct``, each number compared beside its limit, and how
+    many tokens were compared."""
     reqs = run["requests"]
     ok = [r for r in run["measured"] if finished(reqs[r])]
     vocab = c["vocab_size"]
@@ -327,7 +361,7 @@ def check(c: dict, seed: int, run: dict) -> tuple[bool, dict, int]:
         rng = np.random.default_rng([seed & (2**64 - 1), 3])
         pick = rng.permutation(len(rest))[: CHECK_ROWS - 1]
         sample = [longest] + [rest[j] for j in sorted(pick)]
-    ref = importlib.import_module(f"bench.reference.{c['reference']}")
+    ref = reference(c, base)
     widest = None
     served = 0
     if sample and not bad_vocab:
@@ -367,8 +401,9 @@ def run_cell(bench: dict, cell: dict, c: dict, mix: dict, *, seed: int,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     _listen_for_compiles()
 
-    shapes = counts.Shapes.from_config(c)
-    eng = build(c, seed, devices)
+    fam = family(c, base)
+    shapes = fam.shapes(c)
+    eng = build(c, seed, devices, base)
     spans = None
     try:
         block = c["serving"]["decode_block"]
@@ -377,7 +412,7 @@ def run_cell(bench: dict, cell: dict, c: dict, mix: dict, *, seed: int,
         trace_box = {}
         if trace:
             spans = tr.Spans()
-            install_spans(eng, shapes, spans)
+            install_spans(eng, shapes, spans, getattr(fam, "parts", None))
             trace_box["dir"] = tempfile.mkdtemp(prefix="bench-trace-")
             mid = max(0.0, (seconds - TRACE_S) / 2)
 
@@ -412,7 +447,8 @@ def run_cell(bench: dict, cell: dict, c: dict, mix: dict, *, seed: int,
             if on_tick is not None:
                 on_tick(now, t0, t1)
 
-        run = drive(eng, gen, mix, seconds, on_tick=tick)
+        run = drive(eng, gen, mix, seconds, on_tick=tick,
+                    program=getattr(fam, "counters", None))
         if trace:
             trace_box["start"].join()
             if "stop" not in trace_box:
@@ -456,13 +492,17 @@ def run_cell(bench: dict, cell: dict, c: dict, mix: dict, *, seed: int,
                                "idle_gaps": ctx.trace["idle_gaps"]}
         log(f"trace: {len(ctx.trace['calls'])} calls matched, busy "
             f"{ctx.trace['busy_s']} s of {ctx.trace['window_s']} s")
+        for kind in tr.EXECUTABLES:
+            log(f"trace: device s of the matched {kind} calls "
+                f"{sum(x[3] for x in ctx.trace['calls'] if x[0] == kind)}")
+        log(f"trace: device s by scope {json.dumps(ctx.trace['scope_time'])}")
     section = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in metrics_of(bench, section, cell["name"]):
         value = reader(m["name"], base)(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    correct, checks, compared = check(c, seed, run)
+    correct, checks, compared = check(c, seed, run, base)
     log(f"reference: {compared} served tokens of {CHECK_ROWS} requests "
         "compared")
     attempted = len(measured)
@@ -490,12 +530,13 @@ def _listen_for_compiles() -> None:
 
 
 def reduce_trace(box: dict, spans: tr.Spans, plane_of: dict) -> dict:
-    events = tr.load(box["dir"])
+    scopes: dict = {}
+    events = tr.load(box["dir"], scopes)
     offset = tr.host_to_trace_offset(events, spans.host_start)
     if offset is None:
         raise RuntimeError("no benchmark span found in the trace")
     window = (box["w0"] + offset, box["w1"] + offset)
-    return tr.reduce(events, window, plane_of, spans)
+    return tr.reduce(events, window, plane_of, spans, scopes)
 
 
 def device_record(devices, mem: int) -> dict:

@@ -68,8 +68,8 @@ def instrument():
         warm(eng, lengths, block)
         box["log"] = eng.enable_spans(CAPACITY)
 
-    def counters_with_lanes(eng):
-        out = counters(eng)
+    def counters_with_lanes(eng, program=None):
+        out = counters(eng, program)
         for key in LANES:
             out[key] = sum(getattr(e, key) for _, e, _ in run.replicas(eng))
         return out
@@ -78,8 +78,8 @@ def instrument():
         box["run"] = drive(*a, **k)
         return box["run"]
 
-    def load_kept(trace_dir):
-        box["events"] = load(trace_dir)
+    def load_kept(trace_dir, scopes=None):
+        box["events"] = load(trace_dir, scopes)
         return box["events"]
 
     def reduce_kept(trace_box, bench_spans, plane_of):

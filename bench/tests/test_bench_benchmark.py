@@ -65,6 +65,14 @@ def test_config_file_states_what_was_cut(c):
     assert data["serving"]["param_dtype"] == "bfloat16"
 
 
+@pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_finds_its_family_and_reference(c):
+    data = json.loads((ROOT / c["file"]).read_text())
+    assert (ROOT / "bench" / "families" / f"{data['family']}.py").is_file()
+    assert (ROOT / "bench" / "reference"
+            / f"{data['reference']}.py").is_file()
+
+
 def test_every_metric_has_a_reader_and_every_cell_its_metrics():
     cells = {w["name"] for w in BENCH["workloads"]}
     e2e = {m["name"] for m in BENCH["end_to_end"]}

@@ -20,7 +20,7 @@ SEED = 2**31 + 3
 def test_reference_matches_the_program_forward(bias, kv_heads):
     from repro.models.api import build_model
 
-    from bench.run import program_config
+    from bench.families.dense import program_config
 
     c = dict(tiny.CONFIG, qkv_bias=bias, num_key_value_heads=kv_heads)
     cfg = dataclasses.replace(program_config(c), dtype="float32",
